@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 from .modes import CommConfig, CommMode
 

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 from repro.core import attrs as _attrs
 from repro.core import collectives as C

@@ -20,7 +20,7 @@ def _stage_copy_kernel(x_ref, o_ref):
 
 
 def stage_copy_tpu(x: jax.Array, *, wire_bf16: bool = False,
-                   block_rows: int = 128, interpret: bool = True
+                   block_rows: int = 128, interpret: bool = False
                    ) -> jax.Array:
     """x (k, e) -> staged (k, e) in the wire dtype (bf16 when
     compressing an f32 burst, else x.dtype)."""

@@ -11,20 +11,21 @@ from .kernel import stage_copy_tpu
 from .ref import _rows_to_bytes
 
 
-@functools.partial(jax.jit, static_argnames=("wire_bf16",))
-def stage_copy(payloads: jax.Array, *, wire_bf16: bool = False
-               ) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("wire_bf16", "interpret"))
+def stage_copy(payloads: jax.Array, *, wire_bf16: bool = False,
+               interpret: bool = False) -> jax.Array:
     """(k, e) payloads -> (k, row_bytes) packed uint8 wire image, one
     dispatch: the Pallas tile copy applies the wire-dtype cast and the
-    byte view is a free bitcast on the staged result."""
+    byte view is a free bitcast on the staged result.  ``interpret``
+    runs the kernel in the Pallas interpreter (off the TPU)."""
     staged = stage_copy_tpu(payloads, wire_bf16=wire_bf16,
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=interpret)
     return _rows_to_bytes(staged)
 
 
-@functools.partial(jax.jit, static_argnames=("wire_bf16",))
+@functools.partial(jax.jit, static_argnames=("wire_bf16", "interpret"))
 def stage_copy_push(pool, buf, lane, payloads, steal_seed, *,
-                    wire_bf16: bool = False):
+                    wire_bf16: bool = False, interpret: bool = False):
     """The fused stage-copy-push: ONE dispatch stages the doorbell's
     payloads into wire bytes (bf16-compressing when asked), pops a burst
     of packet slots, and scatters the wire rows into the pool's backing
@@ -32,6 +33,6 @@ def stage_copy_push(pool, buf, lane, payloads, steal_seed, *,
     :func:`repro.core.packet_pool.pool_get_copy_n`'s contract — on a
     short grab only the allocated prefix is written."""
     staged = stage_copy_tpu(payloads, wire_bf16=wire_bf16,
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=interpret)
     rows = _rows_to_bytes(staged)
     return pool_get_copy_n(pool, buf, lane, rows, steal_seed)

@@ -25,16 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params(dimension_semantics, interpret: bool):
-    if interpret:
-        return None
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):     # older pallas naming
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=dimension_semantics)
-
-
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                  scale: float, causal: bool, window: int, q_offset: int,
                  block_q: int, block_k: int, n_k: int):
@@ -86,7 +76,7 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
                         q_offset: int = 0, block_q: int = 128,
                         block_k: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """q (b, hq, sq, dh); k/v (b, hkv, skv, dh) -> (b, hq, sq, dh)."""
     b, hq, sq, dh = q.shape
     _, hkv, skv, _ = k.shape
@@ -126,6 +116,6 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, dh), jnp.float32),    # accumulator
         ],
         interpret=interpret,
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary"), interpret),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
     )(q, k, v)

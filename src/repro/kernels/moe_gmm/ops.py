@@ -6,10 +6,10 @@ import functools
 import jax
 
 from .kernel import moe_gmm_tpu
-from .ref import moe_gmm_ref
 
 
-@functools.partial(jax.jit, static_argnames=("act", "block_c"))
-def moe_gmm(x, w1, w2, *, act: str = "swiglu", block_c: int = 128):
+@functools.partial(jax.jit, static_argnames=("act", "block_c", "interpret"))
+def moe_gmm(x, w1, w2, *, act: str = "swiglu", block_c: int = 128,
+            interpret: bool = False):
     return moe_gmm_tpu(x, w1, w2, act=act, block_c=block_c,
-                       interpret=jax.default_backend() != "tpu")
+                       interpret=interpret)

@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_tpu(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-                block_rows: int = 256, interpret: bool = True) -> jax.Array:
+                block_rows: int = 256, interpret: bool = False) -> jax.Array:
     """x (rows, d); w (d,) -> (rows, d)."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)

@@ -6,14 +6,15 @@ import functools
 import jax
 
 from .kernel import rmsnorm_tpu
-from .ref import rmsnorm_ref
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
-def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256):
+@functools.partial(jax.jit,
+                   static_argnames=("eps", "block_rows", "interpret"))
+def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256,
+            interpret: bool = False):
     """x (..., d); w (d,)."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     out = rmsnorm_tpu(x2, w, eps=eps, block_rows=block_rows,
-                      interpret=jax.default_backend() != "tpu")
+                      interpret=interpret)
     return out.reshape(shape)

@@ -6,11 +6,11 @@ import functools
 import jax
 
 from .kernel import ssd_scan_tpu
-from .ref import ssd_scan_ref
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 128):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 128,
+             interpret: bool = False):
     """Seq-major API matching repro.models.ssm.ssd_scan:
     x (s, bs, h, p); dt (s, bs, h); b/c (s, bs, g, n) -> (s, bs, h, p)."""
     xt = x.transpose(1, 2, 0, 3)
@@ -18,5 +18,5 @@ def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 128):
     bt = b.transpose(1, 2, 0, 3)
     ct = c.transpose(1, 2, 0, 3)
     out = ssd_scan_tpu(xt, dtt, a_log, bt, ct, d_skip, chunk=chunk,
-                       interpret=jax.default_backend() != "tpu")
+                       interpret=interpret)
     return out.transpose(2, 0, 1, 3)

@@ -14,21 +14,17 @@ Usage::
 
 Artifacts: benchmarks/artifacts/dryrun/<arch>__<shape>__<mesh>__<mode>.json
 """
-# The first two lines MUST precede any other import (jax locks the device
-# count on first init):
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import json
 import math
+import os
 import re
 import subprocess
 import sys
 import time
 from typing import Any, Dict, Optional, Tuple
 
-from repro.compat import shard_map
+from jax import shard_map
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +45,25 @@ ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "benchmarks", "artifacts", "dryrun")
 
 SDS = jax.ShapeDtypeStruct
+
+# Published per-chip peaks, keyed by jax's ``device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s chip-to-chip interconnect per chip, over the four links
+# of the 2-D torus.
+PEAKS = {
+    "TPU v5 lite": {"bf16_flop_s": 197e12, "hbm_byte_s": 819e9,
+                    "ici_bit_s": 1_600e9, "ici_links": 4},
+}
+# the dry run compiles for CPU stand-ins of a v5e pod slice
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip; a device without published peaks is an error."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; add them to PEAKS with a source")
+    return PEAKS[device_kind]
 
 
 # ---------------------------------------------------------------------------
@@ -323,7 +338,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, mode: CommMode,
         "active_params": cfg.active_param_count(),
         "analytic": analytic.as_dict(),
     }
-    # roofline terms (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link
+    # roofline terms from the target chip's published peaks
+    pk = peaks(TARGET_KIND)
+    art["device_kind"] = TARGET_KIND
     n_dev = mesh.devices.size
     if shape.kind == "train":
         model_flops = 6.0 * cfg.active_param_count() * shape.seq_len \
@@ -334,9 +351,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, mode: CommMode,
     else:
         model_flops = 2.0 * cfg.active_param_count() \
             * shape.global_batch / n_dev
-    t_c = analytic.flops / 197e12
-    t_m = analytic.dot_bytes / 819e9
-    t_l = analytic.link_bytes / 50e9
+    t_c = analytic.flops / pk["bf16_flop_s"]
+    t_m = analytic.dot_bytes / pk["hbm_byte_s"]
+    t_l = analytic.link_bytes / (pk["ici_bit_s"] / 8 / pk["ici_links"])
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_l),
               key=lambda kv: kv[1])
     # Overlap-aware bounds — the paper's claim made measurable on TPU:
@@ -394,6 +411,8 @@ def _save_ops(tag: str, ops) -> None:
 # ---------------------------------------------------------------------------
 
 def main():
+    # before any backend starts: jax fixes the device count on first use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -16,16 +16,23 @@ importing this module never touches jax device state.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.modes import CommConfig, CommMode
 from repro.core.progress import EndpointSpec
 from repro.distributed.comm import Comm
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """``jax.make_mesh`` with every axis in Auto mode: the steps are
+    written for manual ``shard_map`` SPMD under jit-propagated
+    shardings, not for jax's default Explicit axes."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         (AxisType.Auto,) * len(axis_names), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -277,6 +277,9 @@ def preflight(strict: bool = False,
 def _child_env(rank: int, n_ranks: int, session: str, backend: str,
                attr_overrides: Dict[str, str]) -> Dict[str, str]:
     env = dict(os.environ)
+    # ranks are host-runtime processes: a chip belongs to one process, so
+    # no rank may open the accelerator (its parent or a server may hold it)
+    env["JAX_PLATFORMS"] = "cpu"
     env[RANK_ENV] = str(rank)
     env[NRANKS_ENV] = str(n_ranks)
     env[SESSION_ENV] = session

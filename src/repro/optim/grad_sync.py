@@ -29,7 +29,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 from repro.core import collectives as C
 from repro.distributed.comm import Comm, _axes

@@ -42,7 +42,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 from repro.distributed.comm import Comm, _axes, local_comm
 from repro.models.attention import (combine_decode_partials, decode_attention)

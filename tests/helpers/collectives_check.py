@@ -5,7 +5,8 @@ import sys
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import collectives as C
 from repro.core.modes import CommConfig, CommMode
 

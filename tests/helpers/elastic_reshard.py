@@ -8,14 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
 
 from repro.checkpoint import CheckpointStore
 from repro.core.modes import CommConfig, CommMode
 from repro.data import SyntheticPipeline
 from repro.distributed.comm import Comm
 from repro.distributed.elastic import compatible_meshes, reshard_state
-from repro.launch.mesh import shard
+from repro.launch.mesh import make_mesh, shard
 from repro.models.common import ModelConfig
 from repro.models.registry import build_model
 from repro.optim import AdamWConfig

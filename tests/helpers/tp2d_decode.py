@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 
 from repro.core.modes import CommConfig, CommMode
 from repro.distributed.comm import Comm, local_comm

@@ -10,10 +10,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                               # bare env: seeded fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CommConfig, CommDesc, CommKind, HostMatchingEngine,
                         HostPacketPool, LocalCluster, MatchKind,

@@ -11,10 +11,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # bare env: seeded-random fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ErrorCode, LocalCluster, post_am, post_recv
 from repro.core.transport.chaos import ChaosConfig, ChaosTransport

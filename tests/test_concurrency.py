@@ -31,10 +31,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                      # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (LCQ, AtomicCounter, AtomicCredit, AtomicFlag,
                         BacklogQueue, CommConfig, EndpointSpec, FatalError,

@@ -4,10 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # bare env: seeded-random fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (BacklogQueue, CompletionGraph, CompletionHandler,
                         CompletionQueue, ErrorCode, FatalError,

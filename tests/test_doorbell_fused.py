@@ -15,10 +15,7 @@ import jax.numpy as jnp
 import ml_dtypes
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                              # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CommConfig, CommDesc, CommKind, HostMatchingEngine,
                         LocalCluster, MatchKind, MatchingPolicy, PackedBurst,
@@ -231,11 +228,12 @@ class TestDoorbellKernel:
         x = jnp.asarray(np.random.RandomState(0)
                         .randn(16, 5).astype(np.float32))
         for bf16 in (False, True):
-            out = np.asarray(stage_copy(x, wire_bf16=bf16))
+            out = np.asarray(stage_copy(x, wire_bf16=bf16, interpret=True))
             ref = np.asarray(stage_copy_ref(x, wire_bf16=bf16))
             assert np.array_equal(out, ref)
         assert np.array_equal(
-            np.asarray(stage_copy(x)).view(np.float32), np.asarray(x))
+            np.asarray(stage_copy(x, interpret=True)).view(np.float32),
+            np.asarray(x))
 
     def test_stage_copy_push_lands_in_packets(self):
         from repro.kernels.doorbell import stage_copy, stage_copy_push
@@ -243,10 +241,10 @@ class TestDoorbellKernel:
                         .randn(4, 3).astype(np.float32))
         pool = init_pool(n_lanes=1, packets_per_lane=8)
         buf = init_buffers(8, 32)
-        pool, buf, ids, got, status = stage_copy_push(pool, buf, 0, x, 0,
-                                                      wire_bf16=True)
+        pool, buf, ids, got, status = stage_copy_push(
+            pool, buf, 0, x, 0, wire_bf16=True, interpret=True)
         assert int(got) == 4 and int(status) == 0
-        want = np.asarray(stage_copy(x, wire_bf16=True))
+        want = np.asarray(stage_copy(x, wire_bf16=True, interpret=True))
         for i, pid in enumerate(np.asarray(ids)):
             assert np.array_equal(np.asarray(buf[int(pid)])[:6], want[i])
 

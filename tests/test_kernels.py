@@ -10,7 +10,7 @@ from repro.kernels.rmsnorm.kernel import rmsnorm_tpu
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
 from repro.kernels.ssd_scan.kernel import ssd_scan_tpu
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
-from repro.kernels.moe_gmm.kernel import moe_gmm_tpu
+from repro.kernels.moe_gmm.kernel import BLOCK_F, moe_gmm_tpu
 from repro.kernels.moe_gmm.ref import moe_gmm_ref
 
 
@@ -80,7 +80,8 @@ def test_ssd_sweep(bs, h, s, p, g, n, chunk, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("act", ["swiglu", "geglu", "gelu", "relu2"])
 @pytest.mark.parametrize("e,cap,d,f,block", [(4, 32, 48, 24, 8),
-                                             (2, 64, 32, 64, 32)])
+                                             (2, 64, 32, 64, 32),
+                                             (2, 32, 32, 2 * BLOCK_F, 16)])
 def test_moe_gmm_sweep(e, cap, d, f, block, act, dtype):
     mult = 2 if act in ("swiglu", "geglu") else 1
     ks = jax.random.split(jax.random.PRNGKey(0), 3)

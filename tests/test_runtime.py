@@ -4,10 +4,7 @@ property."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # bare env: seeded-random fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CommConfig, LocalCluster, MatchingPolicy, Protocol,
                         post_am_x, post_get_x, post_put_x, post_recv_x,

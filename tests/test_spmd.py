@@ -105,6 +105,18 @@ class TestLauncher:
             os.environ.update(old)
         assert code == 0
 
+    def test_ranks_never_open_the_accelerator(self, monkeypatch):
+        """Ranks are host-only: whatever platform the parent asks for,
+        every child runs with JAX_PLATFORMS=cpu, so no rank can take a
+        chip its parent (or a server beside it) holds."""
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        probe = ("import os, sys; "
+                 "sys.exit(os.environ.get('JAX_PLATFORMS') != 'cpu')")
+        assert spmd.launch([sys.executable, "-c", probe], 2,
+                           backend="shm", timeout=60) == 0
+        env = spmd._child_env(0, 2, "/nonexistent", "shm", {})
+        assert env["JAX_PLATFORMS"] == "cpu"
+
     def test_rank_death_reaps_group_nonzero_exit(self):
         """Satellite: one rank dies mid-window (exit 3) while its peer
         would happily spin forever; the launcher must kill the survivor's

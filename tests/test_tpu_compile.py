@@ -1,0 +1,112 @@
+"""Compile the main path's kernels and the olmo-1b serve step for a
+described TPU v5e chip, at real widths.
+
+Nothing runs: ``lower(...).compile()`` against a ``v5e:2x2`` topology
+raises what the chip's compiler would raise (tile-misaligned blocks,
+scoped-VMEM overflow, a program that does not fit HBM).  The topology is
+described inside a module fixture, never at import time, because only
+one process at a time may load the TPU compiler library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.doorbell.ops import stage_copy
+from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.moe_gmm.kernel import moe_gmm_tpu
+from repro.kernels.rmsnorm.kernel import rmsnorm_tpu
+from repro.kernels.ssd_scan.kernel import ssd_scan_tpu
+from repro.models.registry import build_model
+from repro.serving.engine import init_cache, make_serve_step
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler library / plug-in here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip; keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile_kernel(fn, *args):
+    """Compile for the described chip; the Pallas kernel must be in the
+    program as a Mosaic custom call, not interpreted."""
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_attention_olmo_1b(one_chip):
+    cfg = get_config("olmo-1b")
+    dh = cfg.d_model // cfg.n_heads
+    q = _sds((1, cfg.n_heads, 2048, dh), jnp.bfloat16, one_chip)
+    _compile_kernel(lambda q, k, v: flash_attention_tpu(q, k, v),
+                    q, q, q)
+
+
+def test_rmsnorm_d2048(one_chip):
+    _compile_kernel(rmsnorm_tpu, _sds((4096, 2048), jnp.bfloat16, one_chip),
+                    _sds((2048,), jnp.bfloat16, one_chip))
+
+
+@pytest.mark.parametrize("wire_bf16,e", [(True, 256), (False, 16)])
+def test_doorbell_stage_copy(one_chip, wire_bf16, e):
+    _compile_kernel(lambda x: stage_copy(x, wire_bf16=wire_bf16),
+                    _sds((64, e), jnp.float32, one_chip))
+
+
+def test_moe_gmm_olmoe_1b_7b(one_chip):
+    cfg = get_config("olmoe-1b-7b")
+    e, cap, d, f = cfg.n_experts, 128, cfg.d_model, cfg.d_ff
+    _compile_kernel(lambda x, w1, w2: moe_gmm_tpu(x, w1, w2, act="swiglu"),
+                    _sds((e, cap, d), jnp.bfloat16, one_chip),
+                    _sds((e, d, 2 * f), jnp.bfloat16, one_chip),
+                    _sds((e, f, d), jnp.bfloat16, one_chip))
+
+
+def test_ssd_scan_mamba2_370m(one_chip):
+    cfg = get_config("mamba2-370m")
+    h, p, n = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
+    bs, s, g = 1, 2048, cfg.ssm_groups
+    f32 = jnp.float32
+    bf16 = jnp.bfloat16
+    _compile_kernel(lambda x, dt, a, b, c, d: ssd_scan_tpu(
+                        x, dt, a, b, c, d, chunk=cfg.ssm_chunk),
+                    _sds((bs, h, s, p), bf16, one_chip),
+                    _sds((bs, h, s), bf16, one_chip),
+                    _sds((h,), f32, one_chip),
+                    _sds((bs, g, s, n), bf16, one_chip),
+                    _sds((bs, g, s, n), bf16, one_chip),
+                    _sds((h,), f32, one_chip))
+
+
+def test_olmo_1b_serve_step_fits_one_chip(one_chip):
+    cfg = get_config("olmo-1b")
+    params, _ = build_model(cfg).abstract_params()
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2048, 16))
+    on_chip = lambda t: jax.tree_util.tree_map(          # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        _sds((16,), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB on a 16 GiB chip"

@@ -8,10 +8,7 @@ import threading
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                               # bare env: seeded fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (AttrError, LocalCluster, PackedBurst, Transport,
                         backend_class, decode_msg, encode_msg,
