@@ -264,19 +264,34 @@ def main():
     #       attr.  telemetry_level=off (default) is a one-branch no-op
     #       on every hot path; "counters" unifies every legacy counter
     #       into one snapshot; "timers" adds per-stage span histograms;
-    #       "trace" adds a Chrome-loadable timeline. -------------------
-    import json as _json
+    #       "trace" also puts every span into a running jax.profiler
+    #       trace, on the clock of the device's operations. ------------
+    import glob as _glob
     import tempfile as _tempfile
+    import jax
+    from jax.profiler import ProfileData
+    from repro.core.telemetry import STAGES
     ocl = LocalCluster(2, attrs={"telemetry_level": "trace",
                                  "eager_max_bytes": 1})  # bufcopy -> pool
     ocq = ocl[1].alloc_cq()
     orc = ocl[1].register_rcomp(ocq)
-    for _ in range(32):
-        post_am_x(ocl[0], 1, np.zeros(8, np.uint8), None, None, orc)()
-        ocl.progress_all()
-        while ocq.pop().is_done():
-            pass
-    ocl.quiesce()
+    with _tempfile.TemporaryDirectory() as td:
+        with jax.profiler.trace(td):
+            for _ in range(32):
+                post_am_x(ocl[0], 1, np.zeros(8, np.uint8), None, None,
+                          orc)()
+                ocl.progress_all()
+                while ocq.pop().is_done():
+                    pass
+            ocl.quiesce()
+        (xplane,) = _glob.glob(f"{td}/**/*.xplane.pb", recursive=True)
+        traced = {e.name for plane in ProfileData.from_file(xplane).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name in STAGES}
+    print(f"telemetry: {len(traced)} stages in the profiler's host plane "
+          f"({', '.join(sorted(traced)[:4])}, ...); open the trace "
+          f"directory in TensorBoard or Perfetto")
     snap = ocl.telemetry_snapshot()   # mergeable across ranks/processes
     stages = sorted(snap["spans"])
     print(f"telemetry: level={ocl.get_attr('telemetry_level')} "
@@ -289,12 +304,8 @@ def main():
     # every resource carries its slice as a readonly attr
     print(f"telemetry: device attr block -> "
           f"{ocl[0].default_device.get_attr('telemetry')['counters']}")
-    with _tempfile.TemporaryDirectory() as td:
-        path = ocl.export_trace(f"{td}/trace.json")
-        n_ev = len(_json.load(open(path))["traceEvents"])
-        print(f"telemetry: exported {n_ev} Chrome trace_event slices "
-              f"(load at chrome://tracing); try "
-              f"REPRO_ATTR_TELEMETRY_LEVEL=timers on any benchmark")
+    print("telemetry: try REPRO_ATTR_TELEMETRY_LEVEL=timers on any "
+          "benchmark")
 
     # -- 12. the chaos plane (DESIGN.md §16): faults are attrs too.
     #       Non-zero chaos_* wraps the fabric in a fault-injecting

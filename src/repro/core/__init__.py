@@ -37,8 +37,8 @@ from .progress import (Endpoint, EndpointSpec, Fabric, MemoryRegion,
 from .runtime import (LocalCluster, ProcessCluster, Runtime, g_runtime,
                       g_runtime_fina, g_runtime_init, progress, progress_x)
 from .telemetry import (NULL_TELEMETRY, MetricRegistry, Telemetry,
-                        TraceBuffer, merge_snapshots, record_burst_mix,
-                        render_block, summarize_spans)
+                        merge_snapshots, record_burst_mix, render_block,
+                        summarize_spans)
 from .transport import (Transport, backend_class, decode_msg, encode_msg,
                         make_transport, msg_weight, register_backend)
 from .status import (ErrorCode, ErrorKind, FatalError, Status, done, posted,
@@ -88,9 +88,8 @@ __all__ = [
     "ProgressWorkerPool", "ThreadSafeCompletionQueue", "TryLock",
     "aggregate_lock_stats",
     # telemetry plane (DESIGN.md §15)
-    "NULL_TELEMETRY", "MetricRegistry", "Telemetry", "TraceBuffer",
-    "merge_snapshots", "record_burst_mix", "render_block",
-    "summarize_spans",
+    "NULL_TELEMETRY", "MetricRegistry", "Telemetry", "merge_snapshots",
+    "record_burst_mix", "render_block", "summarize_spans",
     # in-graph collectives
     "collectives",
 ]

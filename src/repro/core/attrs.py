@@ -384,12 +384,8 @@ register_attr("telemetry_level", str, "off",
               choices=("off", "counters", "timers", "trace"),
               doc="observability depth: counters = sharded metric "
                   "registry, timers = stage-scoped spans on every hot "
-                  "path, trace = ring-buffer event trace with Chrome "
-                  "export; off compiles the whole plane away")
-register_attr("trace_capacity", int, 4096, minimum=1,
-              resources=("runtime", "cluster"),
-              doc="per-thread event capacity of the trace ring buffer "
-                  "(old events are overwritten FIFO)")
+                  "path, trace = the spans also in a running "
+                  "jax.profiler trace; off compiles the whole plane away")
 # lock tuning — process-wide (read at lock construction): env mutability
 register_attr("lock_spin_count", int, 4, minimum=0, mutability="env",
               resources=("lock",),

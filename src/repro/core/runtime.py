@@ -41,7 +41,7 @@ RUNTIME_ATTRS = ("mode", "n_channels", "eager_max_bytes", "rdv_threshold",
                  "wire_bf16", "doorbell_fused", "fused_min_burst",
                  "matching_buckets", "matching_locks",
                  "packets_per_lane", "packet_bytes", "pool_lanes",
-                 "telemetry_level", "trace_capacity")
+                 "telemetry_level")
 # Re-exported names that historically lived here (public API compatibility).
 from .progress import (ENDPOINT_ATTRS, RELIABILITY_ATTRS, Endpoint,
                        EndpointSpec, Fabric, MemoryRegion,
@@ -112,8 +112,7 @@ class Runtime(_attrs.AttrResource):
         if ctele is not None and ctele.level == resolved["telemetry_level"]:
             self.tele = ctele
         else:
-            self.tele = Telemetry(resolved["telemetry_level"],
-                                  resolved["trace_capacity"])
+            self.tele = Telemetry(resolved["telemetry_level"])
         # resources (all replicable; these are the process-default set)
         self.matching = HostMatchingEngine(
             resolved["matching_buckets"], resolved["matching_locks"],
@@ -469,7 +468,7 @@ class LocalCluster(_attrs.AttrResource):
         rr = _attrs.resolve(RUNTIME_ATTRS, runtime=self._attr_layer)
         # the cluster-wide telemetry hub: every rank's runtime shares it
         # unless a per-rank config resolves a different level
-        self.tele = Telemetry(rr["telemetry_level"], rr["trace_capacity"])
+        self.tele = Telemetry(rr["telemetry_level"])
         self.fabric = make_transport(
             fr["fabric_backend"], n_ranks, depth=fr["fabric_depth"],
             latency=fr["link_latency"], resolved=fr,
@@ -541,10 +540,6 @@ class LocalCluster(_attrs.AttrResource):
         for rt in self.local_runtimes():
             teles.setdefault(id(rt.tele), rt.tele)
         return merge_snapshots([t.snapshot() for t in teles.values()])
-
-    def export_trace(self, path: str) -> str:
-        """Dump the Chrome trace (``telemetry_level=trace`` runs)."""
-        return self.tele.export_trace(path)
 
     def progress_all(self, rounds: int = 1) -> int:
         """Drive every device of every rank; returns #work events."""

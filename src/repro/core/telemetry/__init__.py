@@ -9,8 +9,13 @@ storage layers and the level gate:
   fold the runtime's long-standing per-resource counters (device
   posts/pushes, protocol stats, pool/matching/lock telemetry) into the
   same snapshot, so one read surfaces everything.
-* :mod:`.timers` — stage-scoped nesting spans over every hot path.
-* :mod:`.trace` — the bounded event trace with Chrome export.
+* :mod:`.timers` — stage-scoped nesting spans over every hot path,
+  and the stage taxonomy (:data:`~.timers.STAGES`).
+
+At ``trace`` level every span also enters a
+``jax.profiler.TraceAnnotation`` of its stage, so a profiler session
+(``jax.profiler.trace(dir)``) records the stages in its own host plane,
+on the same clock as the device's operations.
 
 Levels compose upward (``off < counters < timers < trace``); the level
 is an ordinary attribute (``telemetry_level``, env spelling
@@ -23,14 +28,12 @@ only bookkeeping.
 """
 from __future__ import annotations
 
-import threading
 from typing import Dict, List, Tuple
 
 from .counters import (Histogram, MetricRegistry, merge_counters,
                        merge_hists, merge_snapshots, quantile_bound,
                        record_burst_mix)
-from .timers import NULL_SPAN, SPAN_PREFIX, Span, summarize_spans
-from .trace import TraceBuffer
+from .timers import NULL_SPAN, SPAN_PREFIX, STAGES, Span, summarize_spans
 
 #: telemetry levels, cheapest first; each includes everything before it
 LEVELS = ("off", "counters", "timers", "trace")
@@ -40,9 +43,9 @@ class Telemetry:
     """The attr-controlled observability hub for one cluster/runtime."""
 
     __slots__ = ("level", "counters_on", "timers_on", "trace_on",
-                 "registry", "trace", "_depth", "_collectors")
+                 "registry", "annotation", "_collectors")
 
-    def __init__(self, level: str = "off", trace_capacity: int = 4096):
+    def __init__(self, level: str = "off"):
         if level not in LEVELS:
             raise ValueError(f"unknown telemetry level {level!r}; "
                              f"expected one of {LEVELS}")
@@ -52,8 +55,11 @@ class Telemetry:
         self.timers_on = rank >= 2
         self.trace_on = rank >= 3
         self.registry = MetricRegistry()
-        self.trace = TraceBuffer(trace_capacity) if self.trace_on else None
-        self._depth = threading.local()
+        # the profiler's host-span type, imported only when tracing
+        self.annotation = None
+        if self.trace_on:
+            from jax.profiler import TraceAnnotation
+            self.annotation = TraceAnnotation
         # (prefix, fn) pairs; fn() -> {name: number}.  Many resources may
         # share a prefix (every device attaches under "device"); the
         # snapshot sums overlapping keys, which is the aggregation the
@@ -85,8 +91,9 @@ class Telemetry:
 
     def snapshot(self) -> Dict:
         """The raw, mergeable telemetry document:
-        ``{"level", "counters", "spans"}`` — registry shards merged,
-        collectors sampled, span histograms keyed by stage name."""
+        ``{"level", "counters", "spans", "hists"}`` — registry shards
+        merged, collectors sampled, span histograms keyed by stage name,
+        and the other histograms (``observe``) by their own name."""
         raw = self.registry.snapshot()
         counters = dict(raw["counters"])
         for prefix, fn in self._collectors:
@@ -95,23 +102,14 @@ class Telemetry:
                     continue
                 key = f"{prefix}.{name}"
                 counters[key] = counters.get(key, 0) + value
-        spans = {name[len(SPAN_PREFIX):]: h
-                 for name, h in raw["hists"].items()
-                 if name.startswith(SPAN_PREFIX)}
-        return {"level": self.level, "counters": counters, "spans": spans}
-
-    # -- export --------------------------------------------------------------
-    def chrome_trace(self, pid: int = 0) -> Dict:
-        if self.trace is None:
-            return {"traceEvents": [], "displayTimeUnit": "ms"}
-        return self.trace.chrome_trace(pid)
-
-    def export_trace(self, path: str, pid: int = 0) -> str:
-        """Dump the Chrome ``trace_event`` JSON; returns ``path``."""
-        import json
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(pid), f)
-        return path
+        spans, hists = {}, {}
+        for name, h in raw["hists"].items():
+            if name.startswith(SPAN_PREFIX):
+                spans[name[len(SPAN_PREFIX):]] = h
+            else:
+                hists[name] = h
+        return {"level": self.level, "counters": counters, "spans": spans,
+                "hists": hists}
 
     def __repr__(self) -> str:
         return f"Telemetry(level={self.level!r})"
@@ -133,7 +131,7 @@ def render_block(snapshot: Dict) -> Dict:
 
 __all__ = [
     "LEVELS", "NULL_SPAN", "NULL_TELEMETRY", "SPAN_PREFIX",
-    "Histogram", "MetricRegistry", "Span", "Telemetry", "TraceBuffer",
+    "STAGES", "Histogram", "MetricRegistry", "Span", "Telemetry",
     "merge_counters", "merge_hists", "merge_snapshots",
     "quantile_bound", "record_burst_mix", "render_block",
     "summarize_spans",

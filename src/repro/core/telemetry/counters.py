@@ -156,10 +156,11 @@ def merge_counters(a: Dict, b: Dict) -> Dict:
 
 
 def merge_snapshots(snaps: Iterable[Dict]) -> Dict:
-    """Merge raw telemetry snapshots (one per rank/process): counters and
-    span histograms add elementwise; the effective level is the deepest."""
+    """Merge raw telemetry snapshots (one per rank/process): counters,
+    span histograms and other histograms add elementwise; the effective
+    level is the deepest."""
     from . import LEVELS      # local import: avoid a cycle at module load
-    out: Dict = {"level": "off", "counters": {}, "spans": {}}
+    out: Dict = {"level": "off", "counters": {}, "spans": {}, "hists": {}}
     for snap in snaps:
         if not snap:
             continue
@@ -167,9 +168,11 @@ def merge_snapshots(snaps: Iterable[Dict]) -> Dict:
             out["level"] = snap["level"]
         out["counters"] = merge_counters(out["counters"],
                                          snap.get("counters", {}))
-        for stage, h in snap.get("spans", {}).items():
-            prev = out["spans"].get(stage)
-            out["spans"][stage] = merge_hists(prev, h) if prev else dict(h)
+        for kind in ("spans", "hists"):
+            merged = out[kind]
+            for name, h in snap.get(kind, {}).items():
+                prev = merged.get(name)
+                merged[name] = merge_hists(prev, h) if prev else dict(h)
     return out
 
 

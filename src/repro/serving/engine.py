@@ -76,6 +76,10 @@ jax.tree_util.register_pytree_node(
                 c.length), None),
     lambda _, xs: DecodeCache(*xs))
 
+#: ``jax.named_scope`` of the layer scan's per-layer cache reads and
+#: writes: device-trace readers find the cache traffic by this name
+CACHE_IO = "cache_io"
+
 
 def _has_attn(cfg: ModelConfig) -> bool:
     return cfg.family != "ssm"
@@ -460,10 +464,11 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
             h = apply_norm(cfg.norm, xc, lp.get("norm1"))
             window = layer_window(cfg, idx) if cfg.sliding_window else 0
 
-            kc = kall[idx] if kall is not None else None
-            vc = vall[idx] if vall is not None else None
-            st = sall[idx] if sall is not None else None
-            ct = call_[idx] if call_ is not None else None
+            with jax.named_scope(CACHE_IO):
+                kc = kall[idx] if kall is not None else None
+                vc = vall[idx] if vall is not None else None
+                st = sall[idx] if sall is not None else None
+                ct = call_[idx] if call_ is not None else None
 
             if cfg.family == "ssm":
                 out, st, ct = _decode_ssm(h, lp, cfg, comm, plan, st, ct,
@@ -521,12 +526,13 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                         xc = xc + _decode_mlp(h2, lp, cfg, comm,
                                               tp2d=tp2d)
 
-            if kall is not None and kc is not None:
-                kall = kall.at[idx].set(kc)
-                vall = vall.at[idx].set(vc)
-            if sall is not None and st is not None:
-                sall = sall.at[idx].set(st)
-                call_ = call_.at[idx].set(ct)
+            with jax.named_scope(CACHE_IO):
+                if kall is not None and kc is not None:
+                    kall = kall.at[idx].set(kc)
+                    vall = vall.at[idx].set(vc)
+                if sall is not None and st is not None:
+                    sall = sall.at[idx].set(st)
+                    call_ = call_.at[idx].set(ct)
             return (xc, kall, vall, sall, call_), ()
 
         L_self = cfg.n_layers - n_cross if is_vlm else cfg.n_layers

@@ -33,6 +33,7 @@ from repro.core.concurrency import drain as drain_cq
 from repro.core.matching import HostMatchingEngine, MatchKind
 from repro.core.runtime import LocalCluster
 from repro.core.status import ErrorCode, FatalError, Status, done, posted, retry
+from repro.core.telemetry import NULL_TELEMETRY, Telemetry
 from .kv_cache import PagedKVAllocator
 
 _req_ids = itertools.count()
@@ -50,12 +51,20 @@ class ServeTransport:
       behind a long one on the same stream.
     * ``decode``  — ``n_decode`` device(s), round-robin: the token-return
       path, isolated from all prompt traffic.
+
+    ``tele`` (default: the cluster's hub) times each result on the wire:
+    at ``counters`` level and above, the histogram ``serve.result_wire``
+    gets one sample per result, in nanoseconds from its accepted post in
+    :meth:`send_results` to its pop in :meth:`poll_results`, matched by
+    request id.
     """
 
     def __init__(self, cluster: LocalCluster, *, client_rank: int = 0,
                  server_rank: int = 1, n_prefill: int = 2,
-                 n_decode: int = 1):
+                 n_decode: int = 1, tele: Optional[Telemetry] = None):
         self.cluster = cluster
+        self.tele = cluster.tele if tele is None else tele
+        self._posted_ns: Dict[int, int] = {}    # rid -> result post time
         self.client_rank = client_rank
         self.server_rank = server_rank
         self.prefill = cluster.alloc_endpoint(
@@ -80,13 +89,21 @@ class ServeTransport:
 
     def poll_results(self) -> List[Tuple[int, np.ndarray]]:
         """Drain finished (rid, generated tokens) pairs at the client."""
-        out = []
+        out, tele = [], self.tele
         while True:
             st = self.result_cq.pop()
             if st.is_retry():
                 return out
+            if tele.counters_on:
+                self._observe_wire(st.tag)
             out.append((st.tag, np.asarray(st.get_buffer())
                         .view(np.int32).copy()))
+
+    def _observe_wire(self, rid: int) -> None:
+        t0 = self._posted_ns.pop(rid, None)
+        if t0 is not None:
+            self.tele.observe("serve.result_wire",
+                              time.perf_counter_ns() - t0)
 
     # -- server side ---------------------------------------------------------
     def recv_prompts(self) -> List[Tuple[int, np.ndarray]]:
@@ -99,14 +116,6 @@ class ServeTransport:
             out.append((st.tag, np.asarray(st.get_buffer())
                         .view(np.int32).copy()))
 
-    def send_result(self, rid: int, tokens: np.ndarray) -> Status:
-        """Return generated ids over the decode endpoint (small messages —
-        they stripe onto the isolated decode devices)."""
-        payload = np.ascontiguousarray(tokens, np.int32).view(np.uint8)
-        return self.decode[self.server_rank].post_am(
-            self.client_rank, payload, remote_comp=self._result_rc, tag=rid,
-            allow_retry=False)
-
     def send_results(self, batch: List[Tuple[int, np.ndarray]]
                      ) -> List[Status]:
         """Burst-post a step's finished results in one ``post_am_many``
@@ -116,9 +125,15 @@ class ServeTransport:
         caller's to park (see ``ServeScheduler._flush_results``)."""
         bufs = [np.ascontiguousarray(tokens, np.int32).view(np.uint8)
                 for _, tokens in batch]
-        return self.decode[self.server_rank].post_am_many(
+        t0 = time.perf_counter_ns() if self.tele.counters_on else None
+        sts = self.decode[self.server_rank].post_am_many(
             self.client_rank, bufs, self._result_rc,
             tags=[rid for rid, _ in batch])
+        if t0 is not None:
+            for (rid, _), st in zip(batch, sts):
+                if not st.is_retry():
+                    self._posted_ns[rid] = t0
+        return sts
 
     def pump(self, rounds: int = 4) -> int:
         """Drive progress on both sides' endpoint devices."""
@@ -163,6 +178,11 @@ class ServeScheduler:
     scheduler owns admission, the backlog, and completion delivery.  The
     matching engine routes finished requests back to per-client queues
     (client id = rank, request id = tag — exactly the send/recv pattern).
+
+    The transport's hub (the do-nothing hub without a transport) times
+    each round at ``timers`` level and above: ``sched.step`` over the
+    whole :meth:`step`, inside it ``sched.decode`` (the ``decode_fn``
+    call until its tokens are on the host).
     """
 
     def __init__(self, decode_fn: Callable, *, max_batch: int,
@@ -173,6 +193,8 @@ class ServeScheduler:
         self.alloc = allocator
         self.eos_id = eos_id
         self.transport = transport
+        self.tele = (transport.tele if transport is not None
+                     else NULL_TELEMETRY)
         self.active: Dict[int, Request] = {}
         self.backlog = BacklogQueue()
         self.router = HostMatchingEngine()
@@ -260,6 +282,13 @@ class ServeScheduler:
     # -- engine progress -----------------------------------------------------
     def step(self) -> int:
         """One decode round over the active set; returns #finished."""
+        tele = self.tele
+        if tele.timers_on:
+            with tele.span("sched.step"):
+                return self._step(tele)
+        return self._step(None)
+
+    def _step(self, tele: Optional[Telemetry]) -> int:
         if self.transport is not None:
             self._ingest_transport()
         # redeliver completions a full client CQ rejected earlier — one
@@ -290,7 +319,11 @@ class ServeScheduler:
         tokens = np.array([r.prompt[-1] if not r.generated
                            else r.generated[-1] for r in reqs], np.int32)
         positions = np.array([r.position for r in reqs], np.int32)
-        nxt = np.asarray(self.decode_fn(tokens, positions))
+        if tele is not None:
+            with tele.span("sched.decode"):
+                nxt = np.asarray(self.decode_fn(tokens, positions))
+        else:
+            nxt = np.asarray(self.decode_fn(tokens, positions))
 
         finished = 0
         for r, t in zip(reqs, nxt):

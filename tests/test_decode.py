@@ -1,6 +1,10 @@
 """Serving engine: teacher-forced decode must reproduce the training
-forward's next-token predictions, for every family; plus the paged
-allocator and the continuous-batching scheduler."""
+forward's next-token predictions, for every family; the layer scan's
+cache traffic under its named scope; plus the paged allocator and the
+continuous-batching scheduler."""
+import contextlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,10 +14,11 @@ from repro.core.completion import CompletionQueue
 from repro.distributed.comm import local_comm
 from repro.models.common import ModelConfig
 from repro.models.layers import greedy_sample, lm_head_logits
+from repro.configs import get_smoke
 from repro.models.registry import build_model
 from repro.serving import PagedKVAllocator, ServeScheduler
-from repro.serving.engine import (DecodeCache, init_cache, make_serve_step,
-                                  precompute_cross_kv)
+from repro.serving.engine import (CACHE_IO, DecodeCache, init_cache,
+                                  make_serve_step, precompute_cross_kv)
 
 F = jnp.float32
 S, B = 16, 2
@@ -170,3 +175,61 @@ class TestScheduler:
             assert rounds < 200
         assert sched.completed == 6
         assert alloc.free_pages == 4
+
+
+# ---------------------------------------------------------------------------
+# the cache_io scope: metadata on the layer scan's cache reads and writes
+# ---------------------------------------------------------------------------
+
+SCOPED = ["olmo-1b", "mamba2-370m"]
+_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                    r"([\w-]+)\(.*?(?:op_name=\"([^\"]*)\")?[^\"]*$")
+
+
+def _smoke_hlo(arch):
+    """The CPU-optimized HLO text of the smoke config's serve step."""
+    cfg = get_smoke(arch)
+    params = build_model(cfg).abstract_params()[0]
+    cache = jax.eval_shape(lambda: init_cache(cfg, 16, 4))
+    text = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, jnp.zeros((4,), jnp.int32)).compile().as_text()
+    return text, cache
+
+
+def _strip(hlo):
+    """Instructions and computation headers without their metadata (op
+    names, source lines): the stack-frame tables go too."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", line)
+            for line in hlo.splitlines()
+            if line.lstrip().startswith(("%", "ROOT", "ENTRY", "}"))]
+
+
+@pytest.mark.parametrize("arch", SCOPED)
+def test_cache_io_scope_is_on_every_cache_slice_and_update(arch):
+    hlo, cache = _smoke_hlo(arch)
+    bufs = [a.shape for a in (cache.k, cache.v, cache.ssm_state,
+                              cache.conv_tail) if a is not None]
+    whole = {",".join(map(str, s)) for s in bufs}
+    layer = {",".join(map(str, (1,) + s[1:])) for s in bufs}
+    seen = {"dynamic-slice": 0, "dynamic-update-slice": 0}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        dims, op, name = m.groups()
+        if (op == "dynamic-slice" and dims in layer) or (
+                op == "dynamic-update-slice" and dims in whole):
+            seen[op] += 1
+            assert name and CACHE_IO in name.split("/"), line
+    assert all(seen.values()), seen
+
+
+@pytest.mark.parametrize("arch", SCOPED)
+def test_cache_io_scope_changes_metadata_only(arch, monkeypatch):
+    scoped, _ = _smoke_hlo(arch)
+    assert f"/{CACHE_IO}/" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, _ = _smoke_hlo(arch)
+    assert f"/{CACHE_IO}/" not in plain
+    assert _strip(scoped) == _strip(plain)

@@ -19,6 +19,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import attrs as A
 from repro.core.runtime import LocalCluster
 from repro.core.status import FatalError, done, retry
+from repro.core.telemetry import NULL_TELEMETRY, Telemetry
 from repro.core.transport.socket import SocketTransport
 from repro.core.transport.wire import WireKind, WireMsg
 from repro.serving import (ContinuousBatcher, PagedKVAllocator, ResultDrain,
@@ -388,6 +389,89 @@ class TestSchedulerBurstDelivery:
             assert not sched._pending_sends
         finally:
             cluster.close()
+
+
+# ---------------------------------------------------------------------------
+# scheduler and transport telemetry: sched.* spans, serve.result_wire
+# ---------------------------------------------------------------------------
+
+class TestSchedulerTelemetry:
+    def _run(self, cluster, tele=None, n=5, max_new=(1, 2, 3, 4, 6)):
+        """Serve ``n`` remote requests; returns (rounds, decode calls,
+        results polled, scheduler, transport)."""
+        kw = {} if tele is None else {"tele": tele}
+        transport = ServeTransport(cluster, **kw)
+        calls = []
+
+        def decode_fn(toks, pos):
+            calls.append(len(toks))
+            return (toks + 1) % 997
+
+        sched = ServeScheduler(decode_fn, max_batch=8,
+                               allocator=PagedKVAllocator(n_pages=64,
+                                                          page_size=8),
+                               transport=transport)
+        rids = [sched.submit_remote(np.arange(3, dtype=np.int32), m)
+                for m in max_new[:n]]
+        got, rounds = [], 0
+        while len(got) < len(rids) and rounds < 200:
+            sched.step()
+            transport.pump()
+            got += transport.poll_results()
+            rounds += 1
+        # a few idle rounds: they step but make no decode call
+        for _ in range(3):
+            sched.step()
+            rounds += 1
+        assert sorted(r for r, _ in got) == sorted(rids)
+        return rounds, len(calls), len(got), sched, transport
+
+    def test_span_counts_match_rounds_and_decode_calls(self):
+        cluster = LocalCluster(2)
+        try:
+            tele = Telemetry("timers")
+            rounds, calls, _, sched, transport = self._run(cluster, tele)
+            assert sched.tele is tele and transport.tele is tele
+            spans = tele.snapshot()["spans"]
+            assert set(spans) == {"sched.step", "sched.decode"}
+            assert spans["sched.step"]["count"] == rounds
+            assert spans["sched.decode"]["count"] == calls < rounds
+            assert spans["sched.step"]["sum"] >= spans["sched.decode"]["sum"]
+        finally:
+            cluster.close()
+
+    @pytest.mark.parametrize("level", ["counters", "timers"])
+    def test_result_wire_counts_every_polled_result(self, level):
+        cluster = LocalCluster(2)
+        try:
+            tele = Telemetry(level)
+            _, _, polled, _, transport = self._run(cluster, tele)
+            wire = tele.snapshot()["hists"]["serve.result_wire"]
+            assert wire["count"] == polled == 5
+            assert wire["sum"] > 0
+            assert not transport._posted_ns      # every stamp consumed
+        finally:
+            cluster.close()
+
+    def test_default_hub_is_the_cluster_hub_and_records_nothing(self):
+        cluster = LocalCluster(2, attrs={"telemetry_level": "off"})
+        try:
+            _, _, _, sched, transport = self._run(cluster)
+            assert sched.tele is transport.tele is cluster.tele
+            snap = cluster.tele.snapshot()
+            assert snap["spans"] == {} and snap["hists"] == {}
+            assert not transport._posted_ns
+        finally:
+            cluster.close()
+
+    def test_scheduler_without_transport_defaults_to_the_null_hub(self):
+        sched = ServeScheduler(lambda t, p: t, max_batch=2,
+                               allocator=PagedKVAllocator(n_pages=4,
+                                                          page_size=8))
+        assert sched.tele is NULL_TELEMETRY
+        sched.submit(np.arange(2, dtype=np.int32), 2)
+        sched.step()
+        assert NULL_TELEMETRY.snapshot()["spans"] == {}
 
 
 # ---------------------------------------------------------------------------

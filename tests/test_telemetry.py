@@ -1,20 +1,25 @@
 """The unified telemetry plane (DESIGN.md §15).
 
 Covers the metric registry's sharded-merge guarantee (concurrent adds
-never lose counts), stage-span nesting and summaries, the bounded trace
-ring's wraparound and Chrome export, the off-level zero-allocation
-contract (``span()`` returns one singleton), the ``telemetry`` readonly
-attr on every resource type, burst/scalar protocol-accounting equality
-through :func:`record_burst_mix`, cross-rank snapshot merging, and the
-SPMD hygiene scan benchmarks gate their timing rows on.
+never lose counts), stage-span nesting and summaries, trace-level spans
+read back from a CPU ``jax.profiler`` trace, the stage taxonomy, the
+off-level zero-allocation contract (``span()`` returns one singleton),
+the ``telemetry`` readonly attr on every resource type, burst/scalar
+protocol-accounting equality through :func:`record_burst_mix`,
+cross-rank snapshot merging, and the SPMD hygiene scan benchmarks gate
+their timing rows on.
 """
 import dataclasses
-import json
+import glob
+import os
+import re
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 import repro.core as C
 from repro.core import telemetry as T
@@ -107,15 +112,6 @@ class TestSpans:
         # containment: the outer stage strictly encloses the inner one
         assert spans["outer"]["sum"] >= spans["inner"]["sum"] > 0
 
-    def test_trace_level_events_carry_nesting_depth(self):
-        tele = T.Telemetry("trace", trace_capacity=16)
-        with tele.span("outer"):
-            with tele.span("inner"):
-                pass
-        by_name = {e["name"]: e for e in tele.trace.events()}
-        assert by_name["outer"]["depth"] == 0
-        assert by_name["inner"]["depth"] == 1
-
     def test_summarize_spans_shape(self):
         tele = T.Telemetry("timers")
         with tele.span("s"):
@@ -128,38 +124,86 @@ class TestSpans:
 
 
 # ---------------------------------------------------------------------------
-# trace ring
+# trace level: the spans in the profiler's own trace
 # ---------------------------------------------------------------------------
 
-class TestTrace:
-    def test_wraparound_keeps_the_latest_window(self):
-        buf = T.TraceBuffer(capacity=8)
-        for i in range(20):
-            buf.emit(f"e{i}", t0_ns=i * 10, dur_ns=1)
-        events = buf.events()
-        assert len(events) == 8
-        assert [e["name"] for e in events] == \
-            [f"e{i}" for i in range(12, 20)]
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-    def test_per_thread_lanes_merge_sorted(self):
-        buf = T.TraceBuffer(capacity=8)
-        buf.emit("main", t0_ns=50, dur_ns=1)
-        t = threading.Thread(target=lambda: buf.emit("w", 10, 1),
-                             name="lane-w")
-        t.start()
-        t.join()
-        events = buf.events()
-        assert [e["name"] for e in events] == ["w", "main"]
-        assert {e["lane"] for e in events} == {"lane-w", "MainThread"}
 
-    def test_chrome_trace_document(self, tmp_path):
-        buf = T.TraceBuffer(capacity=4)
-        buf.emit("stage", t0_ns=2000, dur_ns=1500)
-        path = buf.export(str(tmp_path / "trace.json"), pid=3)
-        doc = json.load(open(path))
-        (ev,) = doc["traceEvents"]
-        assert ev == {"name": "stage", "ph": "X", "pid": 3,
-                      "tid": "MainThread", "ts": 2.0, "dur": 1.5}
+def _profiled(fn, tmp_path):
+    """Run ``fn`` under a CPU ``jax.profiler`` session; returns the host
+    plane's events as (name, start_ns, end_ns, line) tuples, one line per
+    thread, numbered in the plane's order."""
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.end_ns, i)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for i, line in enumerate(plane.lines) for e in line.events]
+
+
+class TestProfilerTrace:
+    def test_nested_spans_land_in_the_host_plane(self, tmp_path):
+        tele = T.Telemetry("trace")
+
+        def work():
+            with tele.span("sched.step"):
+                with tele.span("sched.decode"):
+                    time.sleep(0.001)
+
+        events = {e[0]: e for e in _profiled(work, tmp_path)}
+        outer, inner = events["sched.step"], events["sched.decode"]
+        assert outer[1] <= inner[1] < inner[2] <= outer[2]
+        assert inner[2] - inner[1] >= 1_000_000        # the 1 ms sleep
+        # the histograms still record, as at timers level
+        spans = tele.snapshot()["spans"]
+        assert spans["sched.step"]["count"] == spans["sched.decode"][
+            "count"] == 1
+
+    def test_spans_from_two_threads_both_land(self, tmp_path):
+        tele = T.Telemetry("trace")
+
+        def work():
+            t = threading.Thread(target=lambda: tele.span("worker.sweep")
+                                 .__enter__().__exit__(None, None, None))
+            with tele.span("post"):
+                t.start()
+                t.join()
+
+        events = _profiled(work, tmp_path)
+        lines = {e[0]: e[3] for e in events}
+        assert {"post", "worker.sweep"} <= set(lines)
+        assert lines["post"] != lines["worker.sweep"]
+
+    def test_trace_level_cluster_shows_core_stages(self, tmp_path):
+        cl = C.LocalCluster(2, attrs={"telemetry_level": "trace",
+                                      "eager_max_bytes": 1,
+                                      "packets_per_lane": 64})
+        names = {e[0] for e in _profiled(lambda: _drive(cl, iters=8),
+                                         tmp_path)}
+        stages = names & set(T.STAGES)
+        assert len(stages) >= 8, stages
+        assert {"post", "post_burst", "progress", "transport.push",
+                "cq.pop"} <= stages
+
+    @pytest.mark.parametrize("level", ["off", "counters", "timers"])
+    def test_levels_below_trace_leave_no_stage(self, level, tmp_path):
+        cl = C.LocalCluster(2, attrs={"telemetry_level": level,
+                                      "eager_max_bytes": 1})
+        names = {e[0] for e in _profiled(lambda: _drive(cl, iters=8),
+                                         tmp_path)}
+        assert not names & set(T.STAGES)
+
+    def test_every_span_in_the_program_is_a_known_stage(self):
+        used = set()
+        for path in glob.glob(os.path.join(SRC, "**", "*.py"),
+                              recursive=True):
+            with open(path) as f:
+                used |= set(re.findall(r"span\(\s*\"([^\"]+)\"", f.read()))
+        assert len(used) >= 20, used
+        assert used <= set(T.STAGES), used - set(T.STAGES)
 
 
 # ---------------------------------------------------------------------------
@@ -309,16 +353,6 @@ class TestWiredRuntime:
         spans = cl.telemetry_snapshot()["spans"]
         assert "worker.sweep" in spans
         assert "worker.nap" in spans          # idle fabric -> backoff naps
-
-    def test_trace_level_cluster_export(self, tmp_path):
-        cl = C.LocalCluster(2, attrs={"telemetry_level": "trace",
-                                      "trace_capacity": 256})
-        _drive(cl, iters=4)
-        path = cl.export_trace(str(tmp_path / "t.json"))
-        doc = json.load(open(path))
-        events = doc["traceEvents"]
-        assert events and {e["ph"] for e in events} == {"X"}
-        assert {"post", "progress"} <= {e["name"] for e in events}
 
     def test_runtimes_share_the_cluster_hub(self):
         cl = C.LocalCluster(2, attrs={"telemetry_level": "timers"})
