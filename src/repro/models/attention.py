@@ -119,7 +119,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      q_pos=None, block_k: int = 1024) -> tuple:
     """One-token attention against a (possibly sharded) KV slice.
 
-    q: (b, hq, dh); k_cache/v_cache: (skv_local, b, hkv, dh).
+    q: (b, hq, dh); k_cache/v_cache: (skv_local, hkv, b, dh), the serving
+    cache's own order (:mod:`repro.serving.engine`).
     Returns ``(num, m, l)`` — the *partial* flash-decode triple:
     num (b, hq, dh) unnormalized output, m (b, hq) running max, l (b, hq)
     exp-sum.  Shard-parallel callers combine partials across the KV-sharding
@@ -131,7 +132,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     query's global position (defaults to valid_len - 1 + nothing... callers
     pass it explicitly for windowed attention).
     """
-    skv, b, hkv, dh = k_cache.shape
+    skv, hkv, b, dh = k_cache.shape
     hq = q.shape[1]
     g = hq // hkv
     scale = 1.0 / math.sqrt(dh)
@@ -150,21 +151,21 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     bk = _pick_block(skv, block_k)
     nk = skv // bk
-    kb = kf.reshape(nk, bk, b, hkv, dh)
-    vb = vf.reshape(nk, bk, b, hkv, dh)
+    kb = kf.reshape(nk, bk, hkv, b, dh)
+    vb = vf.reshape(nk, bk, hkv, b, dh)
     maskb = valid.reshape(nk, bk)
 
     def step(carry, inputs):
         m, l, acc = carry
         k_blk, v_blk, msk = inputs
-        s = jnp.einsum("bhgd,kbhd->bhgk", qf, k_blk)
+        s = jnp.einsum("bhgd,khbd->bhgk", qf, k_blk)
         s = jnp.where(msk[None, None, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1)
         acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhgk,kbhd->bhgd", p, v_blk)
+            "bhgk,khbd->bhgd", p, v_blk)
         return (m_new, l_new, acc_new), None
 
     m0 = jnp.full((b, hkv, g), NEG_INF, jnp.float32)

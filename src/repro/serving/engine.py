@@ -2,12 +2,17 @@
 
 Cache layout (global view; local view divides by the mesh):
 
-    k/v      (L, S, B, n_kv, dh)     seq-sharded over ``model`` (and over
+    k/v      (L, S, n_kv, B, dh)     seq-sharded over ``model`` (and over
                                      ``data`` too when B == 1: long-context
                                      flash-decode over the joint axis)
     ssm_state (L, B, H, N, P)        heads over ``model``, batch over ``data``
     conv_tail (L, K-1, B, d_inner)   channels with the heads
     cross_k/v (L, T, B, n_kv, dh)    (enc-dec / VLM) precomputed memory KV
+
+K/V are stored in the order the layer scan's loop keeps its carry in, so
+XLA transposes nothing at the loop's edges; each step writes one
+``(n_kv, B, dh)`` row per layer in place and attention reads the layer
+where it lies.
 
 Decode dataflow per layer (the LCI reading: every KV shard is a *channel*;
 partial attention results are joined by a synchronizer — implemented as
@@ -16,7 +21,7 @@ the flash-decode max/sum-exp psum combine):
     x (b, d) replicated over model
       -> q/k/v local head shards   (tiny matmuls)
       -> all-gather q,kv over model (bytes ~ b·h·dh: inject-protocol small)
-      -> cache write at ``pos`` on the owning seq shard
+      -> one-row cache write at (layer, ``pos``) on the owning seq shard
       -> decode_attention against the LOCAL seq shard (all heads)
       -> combine partials (psum/pmax over the KV-sharding axes)
       -> out-projection row shard + psum
@@ -61,7 +66,9 @@ from repro.models import lm as lm_mod
 
 @dataclasses.dataclass
 class DecodeCache:
-    k: Optional[jax.Array] = None            # (L, S_loc, b, n_kv, dh)
+    # (L, S_loc, n_kv, b, dh): the layer loop's own layout, so the donated
+    # cache enters and leaves the loop without a transpose
+    k: Optional[jax.Array] = None
     v: Optional[jax.Array] = None
     ssm_state: Optional[jax.Array] = None    # (L, b, H_loc, N, P)
     conv_tail: Optional[jax.Array] = None    # (L, K-1, b, di_loc)
@@ -105,7 +112,7 @@ def init_cache(cfg: ModelConfig, seq_len: int, batch: int, *,
     c = DecodeCache(length=jnp.zeros((), jnp.int32))
     if _has_attn(cfg):
         dh = cfg.resolved_head_dim
-        shape = (L, seq_len, batch, cfg.n_kv_heads, dh)
+        shape = (L, seq_len, cfg.n_kv_heads, batch, dh)
         c.k = jnp.zeros(shape, cfg.dtype)
         c.v = jnp.zeros(shape, cfg.dtype)
     if _has_ssm(cfg):
@@ -137,8 +144,8 @@ def cache_pspecs(cfg: ModelConfig, *, batch: int, model_axis="model",
     dec = shard_decisions(cfg)
     ssm_head = model_axis if dec["ssm"] else None
     return DecodeCache(
-        k=P(None, seq_axes, batch_spec, None, None) if _has_attn(cfg) else None,
-        v=P(None, seq_axes, batch_spec, None, None) if _has_attn(cfg) else None,
+        k=P(None, seq_axes, None, batch_spec, None) if _has_attn(cfg) else None,
+        v=P(None, seq_axes, None, batch_spec, None) if _has_attn(cfg) else None,
         ssm_state=(P(None, batch_spec, ssm_head, None, None)
                    if _has_ssm(cfg) else None),
         conv_tail=(P(None, None, batch_spec, ssm_head)
@@ -211,10 +218,12 @@ def _row_parallel_out(x_loc, w, *, comm: Comm, tp2d: bool,
 
 
 def _kv_axes(comm: Comm, *, joint: bool):
-    """Axes the KV seq dim is sharded over (model [+ data for B==1])."""
+    """Axes the KV seq dim is sharded over (model [+ data for B==1]), in
+    :func:`cache_pspecs`' order, so shard ``i`` holds global rows
+    ``i * S_loc`` on."""
     axes = list(_axes(comm.model_axis))
     if joint:
-        axes = list(_axes(comm.data_axis)) + axes
+        axes = axes + list(_axes(comm.data_axis))
     return tuple(axes)
 
 
@@ -242,14 +251,27 @@ def _pmax_axes(x, axes):
     return x
 
 
-def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_cache,
-                       v_cache, pos, window, *, joint_kv: bool,
+def _write_row(buf, row, idx, at, owns):
+    """Write one token's ``(n_kv, b, dh)`` row into the whole cache
+    buffer ``(L, S_loc, n_kv, b, dh)`` at (layer ``idx``, row ``at``), in
+    place; a shard that does not own the position writes back the row it
+    read."""
+    start = (idx, at, 0, 0, 0)
+    old = jax.lax.dynamic_slice(buf, start, (1, 1) + buf.shape[2:])
+    new = jnp.where(owns, row.astype(buf.dtype)[None, None], old)
+    return jax.lax.dynamic_update_slice(buf, new, start)
+
+
+def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
+                       idx, pos, window, *, joint_kv: bool,
                        prefix: str = "", memory_kv=None,
                        tp2d: bool = False, defer_out: bool = False):
     """One attention layer for a single token.
 
-    x (b, d) replicated over model; k/v_cache (S_loc, b, nkv, dh) local seq
-    shard.  Returns (out (b, d), k_cache', v_cache').
+    x (b, d) replicated over model; k/v_all (L, S_loc, nkv, b, dh) the
+    whole local seq shard, of which this layer is ``idx`` (all three None
+    for cross-attention over ``memory_kv``).  Returns (out (b, d), k_all',
+    v_all') with the token's row written.
     """
     dh = cfg.resolved_head_dim
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
@@ -302,18 +324,18 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_cache,
         axes = _kv_axes(comm, joint=joint_kv and not batch_sharded)
         if batch_sharded:
             axes = _kv_axes(comm, joint=False)
-        shard_len = k_cache.shape[0]
+        shard_len = k_all.shape[1]
         my_idx = _axes_index(comm, axes)
         my_start = my_idx * shard_len
         rel = pos - my_start
         owns = (rel >= 0) & (rel < shard_len)
         rel_c = jnp.clip(rel, 0, shard_len - 1)
-        k_cache = k_cache.at[rel_c].set(
-            jnp.where(owns, k_new.astype(k_cache.dtype), k_cache[rel_c]))
-        v_cache = v_cache.at[rel_c].set(
-            jnp.where(owns, v_new.astype(v_cache.dtype), v_cache[rel_c]))
+        with jax.named_scope(CACHE_IO):
+            k_all = _write_row(k_all, k_new.swapaxes(0, 1), idx, rel_c, owns)
+            v_all = _write_row(v_all, v_new.swapaxes(0, 1), idx, rel_c, owns)
+            k_layer, v_layer = k_all[idx], v_all[idx]
         num, m, l = decode_attention(
-            q, k_cache, v_cache, valid_len=pos + 1, kv_offset=my_start,
+            q, k_layer, v_layer, valid_len=pos + 1, kv_offset=my_start,
             window=window, q_pos=pos)
         m_g = _pmax_axes(m, axes)
         corr = jnp.exp(m - m_g)
@@ -322,7 +344,8 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_cache,
         attn = (num_g / jnp.maximum(l_g, 1e-37)[..., None])
     else:
         mk, mv = memory_kv                        # (T, b, nkv, dh) local full
-        num, m, l = decode_attention(q, mk, mv, valid_len=None)
+        num, m, l = decode_attention(q, mk.swapaxes(1, 2),
+                                     mv.swapaxes(1, 2), valid_len=None)
         attn = num / jnp.maximum(l, 1e-37)[..., None]
 
     attn = attn.reshape(-1, nq * dh).astype(x.dtype)
@@ -339,16 +362,16 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_cache,
                                                 axis=1)
         if defer_out:
             return (jnp.tensordot(attn_loc, lp[prefix + "wo"], axes=1),
-                    k_cache, v_cache)
+                    k_all, v_all)
         out = _row_parallel_out(attn_loc, lp[prefix + "wo"], comm=comm,
                                 tp2d=tp2d, shard_model=True)
     else:
         if defer_out:
             return (jnp.tensordot(attn, lp[prefix + "wo"], axes=1),
-                    k_cache, v_cache)
+                    k_all, v_all)
         out = _row_parallel_out(attn, lp[prefix + "wo"], comm=comm,
                                 tp2d=tp2d, shard_model=False)
-    return out, k_cache, v_cache
+    return out, k_all, v_all
 
 
 def _decode_mlp(x, lp, cfg, comm: Comm, prefix: str = "",
@@ -465,8 +488,6 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
             window = layer_window(cfg, idx) if cfg.sliding_window else 0
 
             with jax.named_scope(CACHE_IO):
-                kc = kall[idx] if kall is not None else None
-                vc = vall[idx] if vall is not None else None
                 st = sall[idx] if sall is not None else None
                 ct = call_[idx] if call_ is not None else None
 
@@ -475,8 +496,8 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                                           tp2d=tp2d)
                 xc = xc + out
             elif cfg.family == "hybrid":
-                a_out, kc, vc = _decode_attn_layer(
-                    h, lp, cfg, comm, plan, kc, vc, pos, window,
+                a_out, kall, vall = _decode_attn_layer(
+                    h, lp, cfg, comm, plan, kall, vall, idx, pos, window,
                     joint_kv=joint_kv, tp2d=tp2d)
                 s_out, st, ct = _decode_ssm(h, lp, cfg, comm, plan, st, ct,
                                             tp2d=tp2d)
@@ -486,8 +507,8 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 h2 = apply_norm(cfg.norm, xc, lp.get("norm2"))
                 xc = xc + _decode_mlp(h2, lp, cfg, comm, tp2d=tp2d)
             else:
-                a_out, kc, vc = _decode_attn_layer(
-                    h, lp, cfg, comm, plan, kc, vc, pos, window,
+                a_out, kall, vall = _decode_attn_layer(
+                    h, lp, cfg, comm, plan, kall, vall, idx, pos, window,
                     joint_kv=joint_kv, tp2d=tp2d,
                     defer_out=tp2d and cfg.parallel_block)
                 if cfg.parallel_block:
@@ -507,7 +528,7 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                     if cfg.is_encdec and aux_kv is not None:
                         hx = rms_norm(xc, lp["normx"])
                         x_out, _, _ = _decode_attn_layer(
-                            hx, lp, cfg, comm, plan, None, None, pos, 0,
+                            hx, lp, cfg, comm, plan, None, None, None, pos, 0,
                             joint_kv=joint_kv, prefix="x_",
                             memory_kv=aux_kv, tp2d=tp2d)
                         xc = xc + x_out
@@ -527,9 +548,6 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                                               tp2d=tp2d)
 
             with jax.named_scope(CACHE_IO):
-                if kall is not None and kc is not None:
-                    kall = kall.at[idx].set(kc)
-                    vall = vall.at[idx].set(vc)
                 if sall is not None and st is not None:
                     sall = sall.at[idx].set(st)
                     call_ = call_.at[idx].set(ct)
@@ -565,7 +583,7 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 hx = rms_norm(xc, cross_lp["normx"])
                 x_out, _, _ = _decode_attn_layer(
                     hx, cross_lp, cfg, comm, tp_plan(cfg, comm.tp), None,
-                    None, pos, 0, joint_kv=joint_kv, prefix="x_",
+                    None, None, pos, 0, joint_kv=joint_kv, prefix="x_",
                     memory_kv=(xk, xv), tp2d=tp2d)
                 xc = xc + jnp.tanh(cross_lp["gate_attn"]).astype(xc.dtype) \
                     * x_out

@@ -1,5 +1,9 @@
 """Helper: 2D-TP serving (weight-stationary decode) matches the classic
-FSDP-gather decode AND the local oracle on a (2,4) mesh."""
+FSDP-gather decode AND the local oracle on a (2,4) mesh, in its tokens and
+in its K/V cache: halfway through, the rows written match the oracle's
+and the rest are zero, whether the cache is seq-sharded over model (batch
+over data), over data and model jointly (B == 1), or batch-sharded under
+2D-TP."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +20,23 @@ from repro.serving.engine import cache_pspecs, init_cache, make_serve_step
 
 MESH = make_mesh((2, 4), ("data", "model"))
 F = jnp.float32
+
+
+def _kv(cache):
+    return [np.asarray(a) for a in (cache.k, cache.v) if a is not None]
+
+
+def check_cache(cfg, got, want, half):
+    """Each snapshot's K/V equals the oracle's; rows at and past ``half``
+    are still zero halfway through."""
+    for g, w in zip(got, want):
+        for a, b in zip(g, w):
+            # sharded sums round differently: to the oracle's scale
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=2e-5 * np.abs(b).max(),
+                                       err_msg=cfg.name)
+    for a in got[0]:
+        assert not a[:, half:].any(), cfg.name
 
 
 def check(cfg, batch=4):
@@ -36,32 +57,42 @@ def check(cfg, batch=4):
             serve, mesh=MESH, in_specs=(pspecs, cspecs, tok_spec),
             out_specs=(tok_spec, cspecs), check_vma=False))
         cache = init_cache(cfg, S, batch)
-        preds = []
+        preds, snaps = [], []
         for i in range(S):
             nxt, cache = fn(params, cache, tokens[i])
             preds.append(np.asarray(nxt))
-        return np.stack(preds)
+            if i + 1 in (S // 2, S):
+                snaps.append(_kv(cache))
+        return np.stack(preds), snaps
 
     # local oracle
     serve_l = jax.jit(make_serve_step(cfg))
     cache = init_cache(cfg, S, batch)
-    oracle = []
+    oracle, want = [], []
     for i in range(S):
         nxt, cache = serve_l(params, cache, tokens[i])
         oracle.append(np.asarray(nxt))
+        if i + 1 in (S // 2, S):
+            want.append(_kv(cache))
     oracle = np.stack(oracle)
 
-    classic = run(False)
-    tp2d = run(True)
+    classic, classic_kv = run(False)
+    tp2d, tp2d_kv = run(True)
     a1 = (classic == oracle).mean()
     a2 = (tp2d == oracle).mean()
-    print(f"{cfg.name:10s} classic={a1:.3f} tp2d={a2:.3f}")
+    print(f"{cfg.name:10s} b={batch} classic={a1:.3f} tp2d={a2:.3f}")
     assert a1 > 0.95 and a2 > 0.95, (cfg.name, a1, a2)
+    check_cache(cfg, classic_kv, want, S // 2)
+    check_cache(cfg, tp2d_kv, want, S // 2)
 
 
 check(ModelConfig(name="dense", family="dense", n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=4, d_ff=128, vocab=256,
                   tp_target=4, dtype=F))
+# B == 1: the KV seq dim sharded over data and model jointly (joint_kv)
+check(ModelConfig(name="dense", family="dense", n_layers=2, d_model=64,
+                  n_heads=4, n_kv_heads=4, d_ff=128, vocab=256,
+                  tp_target=4, dtype=F), batch=1)
 check(ModelConfig(name="gqa-par", family="dense", n_layers=2, d_model=64,
                   n_heads=8, n_kv_heads=2, d_ff=128, vocab=256, head_dim=16,
                   norm="layernorm", parallel_block=True, tie_embeddings=True,

@@ -1,7 +1,7 @@
 """Serving engine: teacher-forced decode must reproduce the training
-forward's next-token predictions, for every family; the layer scan's
-cache traffic under its named scope; plus the paged allocator and the
-continuous-batching scheduler."""
+forward's next-token predictions, for every family; the K/V rows it
+writes; the layer scan's cache traffic under its named scope; plus the
+paged allocator and the continuous-batching scheduler."""
 import contextlib
 import re
 
@@ -17,6 +17,7 @@ from repro.models.layers import greedy_sample, lm_head_logits
 from repro.configs import get_smoke
 from repro.models.registry import build_model
 from repro.serving import PagedKVAllocator, ServeScheduler
+from repro.serving import engine
 from repro.serving.engine import (CACHE_IO, DecodeCache, init_cache,
                                   make_serve_step, precompute_cross_kv)
 
@@ -24,7 +25,9 @@ F = jnp.float32
 S, B = 16, 2
 
 
-def _agreement(cfg, extra=None, n_mem=0):
+def _decode_inputs(cfg, extra=None, n_mem=0):
+    """Params, teacher-forced tokens (S, B), the batch and an empty cache
+    (with the memory KV for enc-dec and VLM)."""
     m = build_model(cfg)
     params, _ = m.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (S, B), 0, cfg.vocab)
@@ -32,12 +35,6 @@ def _agreement(cfg, extra=None, n_mem=0):
     if extra:
         batch.update(extra)
     comm = local_comm()
-    x, _ = jax.jit(lambda p, bt: m.forward(p, bt, remat=False))(params,
-                                                                batch)
-    head = params.get("lm_head", params["emb"])
-    oracle = jax.vmap(lambda xp: greedy_sample(
-        lm_head_logits(xp, head, comm, real_vocab=cfg.vocab), comm))(x)
-
     cache = init_cache(cfg, S, B, n_memory=n_mem)
     if n_mem:
         if cfg.is_encdec:
@@ -51,6 +48,17 @@ def _agreement(cfg, extra=None, n_mem=0):
         cache = DecodeCache(k=cache.k, v=cache.v, ssm_state=cache.ssm_state,
                             conv_tail=cache.conv_tail, cross_k=ck,
                             cross_v=cv, length=cache.length)
+    return m, params, tokens, batch, cache
+
+
+def _agreement(cfg, extra=None, n_mem=0):
+    m, params, tokens, batch, cache = _decode_inputs(cfg, extra, n_mem)
+    comm = local_comm()
+    x, _ = jax.jit(lambda p, bt: m.forward(p, bt, remat=False))(params,
+                                                                batch)
+    head = params.get("lm_head", params["emb"])
+    oracle = jax.vmap(lambda xp: greedy_sample(
+        lm_head_logits(xp, head, comm, real_vocab=cfg.vocab), comm))(x)
     step = jax.jit(make_serve_step(cfg))
     preds = []
     for i in range(S):
@@ -105,6 +113,49 @@ CASES = {
 def test_decode_matches_forward(name):
     cfg, extra, n_mem = CASES[name]
     assert _agreement(cfg, extra, n_mem) > 0.95
+
+
+def _layer_slice_write(buf, row, idx, at, owns):
+    """The plain reference of the step's K/V write: over the cache in
+    ``(L, S, B, n_kv, dh)`` order, slice layer ``idx`` out, set row ``at``
+    (or write back the row read, on a shard that does not own it), and
+    set the whole layer back; given and returned in the cache's order."""
+    old = buf.transpose(0, 1, 3, 2, 4)
+    layer = old[idx]
+    layer = layer.at[at].set(jnp.where(owns, row.swapaxes(0, 1).astype(
+        buf.dtype), layer[at]))
+    return old.at[idx].set(layer).transpose(0, 1, 3, 2, 4)
+
+
+@pytest.mark.parametrize("name", ["dense", "moe", "hybrid", "whisper",
+                                  "vlm"])
+def test_cache_rows_match_the_layer_slice_write(name, monkeypatch):
+    """After N < S steps, layer l's row p holds, for every p < N, what
+    the layer-slice write puts there; rows p >= N are zero; the tokens
+    are the same."""
+    cfg, extra, n_mem = CASES[name]
+    _, params, tokens, _, cache0 = _decode_inputs(cfg, extra, n_mem)
+    n = S - 5
+
+    def decode():
+        step, cache, out = jax.jit(make_serve_step(cfg)), cache0, []
+        for i in range(n):
+            nxt, cache = step(params, cache, tokens[i])
+            out.append(np.asarray(nxt))
+        return np.stack(out), cache
+
+    toks, got = decode()
+    monkeypatch.setattr(engine, "_write_row", _layer_slice_write)
+    ref_toks, want = decode()
+    np.testing.assert_array_equal(toks, ref_toks)
+    layers = cache0.k.shape[0]
+    for a, b in ((got.k, want.k), (got.v, want.v)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == (layers, S, cfg.n_kv_heads, B,
+                           cfg.resolved_head_dim)
+        np.testing.assert_array_equal(a, b)
+        assert a[:, :n].any(axis=(2, 3, 4)).all()
+        assert not a[:, n:].any()
 
 
 class TestPagedAllocator:

@@ -1,5 +1,6 @@
 """Compile the main path's kernels and the olmo-1b serve step for a
-described TPU v5e chip, at real widths.
+described TPU v5e chip, at real widths; the serve step at the benchmark
+cell's shapes moves no whole K/V buffer.
 
 Nothing runs: ``lower(...).compile()`` against a ``v5e:2x2`` topology
 raises what the chip's compiler would raise (tile-misaligned blocks,
@@ -8,6 +9,7 @@ described inside a module fixture, never at import time, because only
 one process at a time may load the TPU compiler library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,13 @@ from repro.models.registry import build_model
 from repro.serving.engine import init_cache, make_serve_step
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
+
+_HEADER = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+_CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
+#: ops that name or pass on a buffer without writing one
+_VIEWS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
 
 
 @pytest.fixture(scope="module")
@@ -110,3 +119,60 @@ def test_olmo_1b_serve_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB on a 16 GiB chip"
+
+
+def _kernels(hlo: str):
+    """Each instruction outside a fused computation, as (name, opcode,
+    output dims, the opcode of its fused computation's root or None)."""
+    comps, roots, comp = {}, {}, None
+    for line in hlo.splitlines():
+        head = _HEADER.match(line)
+        if head:
+            comp = head.group(1)
+            comps[comp] = []
+            continue
+        m = _INSTR.match(line)
+        if m and comp is not None:
+            calls = _CALLS.search(line)
+            comps[comp].append((m.group(2), m.group(4),
+                                [tuple(int(d) for d in a.split(",") if d)
+                                 for a in _ARRAY.findall(m.group(3))],
+                                calls.group(1) if calls else None))
+            if m.group(1):
+                roots[comp] = m.group(4)
+    fused = {c for ops in comps.values() for _, op, _, c in ops
+             if op == "fusion"}
+    return [(name, op, dims, roots.get(c) if op == "fusion" else None)
+            for comp, ops in comps.items() if comp not in fused
+            for name, op, dims, c in ops]
+
+
+def test_olmo_1b_serve_step_writes_one_kv_row_in_place(one_chip):
+    """At the benchmark cell's shapes (64 rows, cache 512, donated) the
+    step writes each layer's K/V row into the cache in place and reads
+    the layer inside attention: no copy, scatter or slice of a whole
+    cache or a whole layer, and next to no temporary memory."""
+    cfg = get_config("olmo-1b")
+    params, _ = build_model(cfg).abstract_params()
+    cache = jax.eval_shape(lambda: init_cache(cfg, 512, 64))
+    on_chip = lambda t: jax.tree_util.tree_map(          # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        _sds((64,), jnp.int32, one_chip)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * 2**20, f"{temp / 2**30:.2f} GiB of temporaries"
+    # shapes up to order and unit dims: the layout may put them anywhere
+    whole = sorted(cache.k.shape)
+    layer = sorted(cache.k.shape[1:])
+    writes = []
+    for name, op, dims, root in _kernels(compiled.as_text()):
+        for d in dims:
+            d = sorted(x for x in d if x != 1)
+            if d == whole and op not in _VIEWS:
+                assert op == "dynamic-update-slice" or (
+                    op == "fusion" and root == "dynamic-update-slice"), (
+                    name, op, root)
+                writes.append(name)
+            assert d != layer or op in _VIEWS, (name, op, root)
+    assert len(writes) >= 2, writes          # K and V, in the layer loop
