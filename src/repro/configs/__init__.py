@@ -31,6 +31,12 @@ _MODULES = {
 
 ARCH_NAMES = list(_MODULES)
 
+#: configurations reachable by name outside the shape grid: one chip's
+#: share of an expert-parallel deployment (module, attribute)
+_VARIANTS = {
+    "moonlight-16b-a3b-ep8": ("moonshot_v1_16b_a3b", "EP8"),
+}
+
 __all__ = ["ARCH_NAMES", "SHAPES", "Shape", "cells", "get_config",
            "get_smoke", "shape_applicable"]
 
@@ -42,6 +48,9 @@ def _mod(arch: str):
 
 
 def get_config(arch: str) -> ModelConfig:
+    if arch in _VARIANTS:
+        mod, attr = _VARIANTS[arch]
+        return getattr(importlib.import_module(f"repro.configs.{mod}"), attr)
     return _mod(arch).CONFIG
 
 

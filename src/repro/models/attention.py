@@ -44,7 +44,7 @@ def _pick_block(s: int, preferred: int) -> int:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window=0,
                     q_offset=0, block_q: int = 256, block_k: int = 512,
-                    ) -> jax.Array:
+                    scale: Optional[float] = None) -> jax.Array:
     """Chunked attention with online softmax.
 
     q: (sq, b, hq, dh); k/v: (skv, b, hkv, dh) with hq % hkv == 0 (GQA).
@@ -54,13 +54,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (layer-patterned SWA: the 5:1 local/global choice is data, keeping one
     collective path through the scan body); 0/None disables.  ``causal=
     False`` with no window is full bidirectional (encoder/cross-attention).
-    Returns (sq, b, hq, dh) in q.dtype; softmax in fp32.
+    v may be narrower than q and k (latent attention: dv < dh);
+    ``scale`` defaults to 1/sqrt(dh).
+    Returns (sq, b, hq, dv) in q.dtype; softmax in fp32.
     """
     sq, b, hq, dh = q.shape
     skv, _, hkv, _ = k.shape
+    dv = v.shape[-1]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
-    scale = 1.0 / math.sqrt(dh)
+    scale = 1.0 / math.sqrt(dh) if scale is None else scale
 
     bq = _pick_block(sq, block_q)
     bk = _pick_block(skv, block_k)
@@ -69,7 +72,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # (nq, bq, b, hkv, g, dh) — blocked, GQA-grouped
     qb = q.reshape(nq, bq, b, hkv, g, dh).astype(jnp.float32) * scale
     kb = k.reshape(nk, bk, b, hkv, dh).astype(jnp.float32)
-    vb = v.reshape(nk, bk, b, hkv, dh).astype(jnp.float32)
+    vb = v.reshape(nk, bk, b, hkv, dv).astype(jnp.float32)
     q_offset = jnp.asarray(q_offset, jnp.int32)
     use_window = window is not None and not (
         isinstance(window, int) and window == 0)
@@ -100,17 +103,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
         m0 = jnp.full((b, hkv, g, bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, bq), jnp.float32)
-        a0 = jnp.zeros((b, hkv, g, bq, dh), jnp.float32)
+        a0 = jnp.zeros((b, hkv, g, bq, dv), jnp.float32)
         (m, l, acc), _ = lax.scan(
             kv_step, (m0, l0, a0),
             (jnp.arange(nk, dtype=jnp.int32), kb, vb))
         out = acc / jnp.maximum(l, 1e-37)[..., None]
-        # (b, hkv, g, bq, dh) -> (bq, b, hkv, g, dh)
+        # (b, hkv, g, bq, dv) -> (bq, b, hkv, g, dv)
         return jnp.transpose(out, (3, 0, 1, 2, 4))
 
     outs = lax.map(lambda args: q_block(*args),
                    (jnp.arange(nq, dtype=jnp.int32), qb))
-    out = outs.reshape(sq, b, hkv, g, dh).reshape(sq, b, hq, dh)
+    out = outs.reshape(sq, b, hkv, g, dv).reshape(sq, b, hq, dv)
     return out.astype(q.dtype)
 
 

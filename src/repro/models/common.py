@@ -64,6 +64,29 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     router_z_coef: float = 1e-3
+    # "sigmoid": routing as DeepSeek-V3 (noaux_tc), sigmoid scores with a
+    # per-expert bias that only selects; either way the top-k weights are
+    # renormalized, then scaled by routed_scale
+    router_score: str = "softmax"    # softmax | sigmoid
+    routed_scale: float = 1.0
+    # expert parallelism's share: this program holds routed experts
+    # [first_expert, first_expert + experts_held) of n_experts (0 = all);
+    # the router still scores all n_experts
+    experts_held: int = 0
+    first_expert: int = 0
+    # leading dense layers (DeepSeek first_k_dense_replace) and their width
+    first_dense_layers: int = 0
+    dense_ff: int = 0
+
+    # latent attention (MLA, DeepSeek-V2/V3): on when kv_lora_rank > 0.
+    # K and V come from a kv_lora_rank latent (its own RMSNorm) through
+    # per-head up-projections; a qk_rope_head_dim rotary key is shared by
+    # the heads; no q_lora_rank (queries project from the residual)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    norm_eps: Optional[float] = None  # None: each norm kind's default
 
     # SSM (Mamba2 SSD)
     ssm_state: int = 0
@@ -111,6 +134,20 @@ class ModelConfig:
         return _round_up(self.vocab, 128)
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_stacked_layers(self) -> int:
+        """The layers of the scanned stack: all after the leading dense
+        ones."""
+        return self.n_layers - self.first_dense_layers
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -141,27 +178,47 @@ class ModelConfig:
             return (i + 1) % self.swa_every_nth_global == 0
         return False
 
-    def param_count(self) -> int:
-        """Analytic parameter count (embedding included once if tied)."""
+    def _attn_params(self) -> int:
         d, dh = self.d_model, self.resolved_head_dim
         nq, nkv = self.n_heads, self.n_kv_heads
+        if self.is_mla:
+            r, dr = self.kv_lora_rank, self.qk_rope_head_dim
+            dn, dv = self.qk_nope_head_dim, self.v_head_dim
+            return (d * nq * (dn + dr) + d * (r + dr) + r
+                    + r * nq * (dn + dv) + nq * dv * d)
+        return d * (nq * dh) + 2 * d * (nkv * dh) + (nq * dh) * d
+
+    def _expert_params(self) -> int:
+        """One routed expert's weights."""
+        ff_mult = 3 if self.mlp == "swiglu" else 2
+        return ff_mult * self.d_model * self.d_ff
+
+    def param_count(self) -> int:
+        """Analytic parameter count (embedding included once if tied):
+        attention (MLA or not), the experts this program holds, the
+        router and its bias, the shared expert, leading dense layers."""
+        d, dh = self.d_model, self.resolved_head_dim
+        nq, nkv = self.n_heads, self.n_kv_heads
+        ff_mult = 3 if self.mlp == "swiglu" else 2
         per_layer = 0
         if self.family != "ssm":
-            per_layer += d * (nq * dh) + 2 * d * (nkv * dh) + (nq * dh) * d
+            per_layer += self._attn_params()
         if self.family in ("ssm", "hybrid"):
             di = self.ssm_d_inner
             per_layer += d * (2 * di + 2 * self.ssm_groups * self.ssm_state
                               + self.ssm_heads)
             per_layer += di * d + self.ssm_conv_kernel * di + 2 * self.ssm_heads
+        dense_ffn = ff_mult * d * self.d_ff
         if self.n_experts:
-            ff_mult = 3 if self.mlp == "swiglu" else 2
-            per_layer += self.n_experts * ff_mult * d * self.d_ff
+            dense_ffn = ff_mult * d * self.dense_ff
+            per_layer += self.n_experts_held * self._expert_params()
             per_layer += d * self.n_experts                    # router
+            if self.router_score == "sigmoid":
+                per_layer += self.n_experts                    # its bias
             if self.shared_expert_ff:
                 per_layer += ff_mult * d * self.shared_expert_ff
         elif self.d_ff:
-            ff_mult = 3 if self.mlp == "swiglu" else 2
-            per_layer += ff_mult * d * self.d_ff
+            per_layer += dense_ffn
         per_layer += 2 * d                                     # norms
         n_cross = 0
         if self.cross_attn_every:
@@ -169,19 +226,22 @@ class ModelConfig:
         cross = n_cross * (2 * d * (nq * dh) + 2 * d * (nkv * dh))
         emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
         enc = self.encoder_layers * per_layer                  # (approx)
-        return (self.n_layers * per_layer + cross + emb + enc + d)
+        # a leading dense layer: attention and norms as the rest, its own
+        # FFN in place of the experts
+        dense = self.first_dense_layers * (
+            (self._attn_params() if self.family != "ssm" else 0)
+            + dense_ffn + 2 * d)
+        return (self.n_stacked_layers * per_layer + dense + cross + emb + enc
+                + d)
 
     def active_param_count(self) -> int:
-        """Activated parameters per token (MoE: top-k experts only)."""
+        """Activated parameters per token (MoE: top-k experts only; the
+        top-k of the whole router, whatever share this program holds)."""
         if not self.n_experts:
             return self.param_count()
-        full = self.param_count()
-        ff_mult = 3 if self.mlp == "swiglu" else 2
-        all_experts = self.n_layers * self.n_experts * ff_mult * \
-            self.d_model * self.d_ff
-        active = self.n_layers * self.top_k * ff_mult * \
-            self.d_model * self.d_ff
-        return full - all_experts + active
+        per_expert = self.n_stacked_layers * self._expert_params()
+        return self.param_count() + per_expert * (self.top_k
+                                                  - self.n_experts_held)
 
 
 # ---------------------------------------------------------------------------

@@ -44,13 +44,16 @@ def layer_norm(x: jax.Array, w: Optional[jax.Array],
     return y.astype(x.dtype)
 
 
-def apply_norm(kind: str, x: jax.Array, w: Optional[jax.Array]) -> jax.Array:
+def apply_norm(kind: str, x: jax.Array, w: Optional[jax.Array],
+               eps: Optional[float] = None) -> jax.Array:
+    """``eps`` None keeps each kind's default."""
+    kw = {} if eps is None else {"eps": eps}
     if kind == "rmsnorm":
-        return rms_norm(x, w)
+        return rms_norm(x, w, **kw)
     if kind == "layernorm":
-        return layer_norm(x, w)
+        return layer_norm(x, w, **kw)
     if kind == "layernorm_np":          # OLMo: non-parametric LN
-        return layer_norm(x, None)
+        return layer_norm(x, None, **kw)
     raise ValueError(f"unknown norm {kind!r}")
 
 
@@ -71,6 +74,25 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float
     cos = jnp.cos(angles)[:, None, None, :]
     sin = jnp.sin(angles)[:, None, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin,
+                           x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def apply_rope_pairs(x: jax.Array, positions: jax.Array, theta: float
+                     ) -> jax.Array:
+    """Rotary over adjacent pairs ``(x[2i], x[2i+1])`` at frequency ``i``,
+    as DeepSeek-V2/V3's modelling code pairs them (it de-interleaves and
+    rotates halves; the result is returned here in that de-interleaved
+    order, which both q and k share, so their dot product is the same).
+    x: (s, b, h, dh); positions: (s,)."""
+    dh = x.shape[-1]
+    freqs = rope_frequencies(dh, theta)
+    angles = positions.astype(jnp.float32)[:, None] * freqs
+    cos = jnp.cos(angles)[:, None, None, :]
+    sin = jnp.sin(angles)[:, None, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

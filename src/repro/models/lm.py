@@ -22,11 +22,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.comm import Comm
+from .attention import flash_attention
 from .blocks import (TPPlan, attention_op, init_attention, init_mlp,
                      layer_window, swa_attention_op, tp_plan)
 from .common import ModelConfig, ParamFactory, ParamSpec
-from .layers import (apply_norm, embed_tokens, lm_head_loss, mlp_block,
-                     rms_norm, sinusoidal_positions)
+from .layers import (apply_norm, apply_rope_pairs, embed_tokens,
+                     lm_head_loss, mlp_block, rms_norm, sinusoidal_positions)
 from .moe import init_moe, moe_block
 from .ssm import init_ssm, ssm_op
 
@@ -41,12 +42,76 @@ def _init_norm(pf: ParamFactory, cfg: ModelConfig, name: str, L: int):
     return {name: pf.ones(name, (L, cfg.d_model), stacked=True)}
 
 
+#: latent attention's RMSNorm on the kv latent keeps DeepSeek's default
+#: eps (its layer norms take the config's)
+MLA_KV_NORM_EPS = 1e-6
+
+
+def init_mla(pf: ParamFactory, cfg: ModelConfig, L: int
+             ) -> Dict[str, jax.Array]:
+    """Latent attention (DeepSeek-V2/V3, no q_lora_rank): ``wq`` d ->
+    heads x (nope + rope); ``wkv_a`` d -> latent + shared rope key;
+    ``kv_norm`` on the latent; ``wk_b`` and ``wv_b`` the per-head halves
+    of DeepSeek's ``kv_b_proj`` (latent -> heads x nope, heads x v);
+    ``wo`` heads x v -> d.  Replicated over the model axis (attention is
+    data-parallel), FSDP over data."""
+    d, nq, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": pf.dense("wq", (L, d, nq * (dn + dr)), tp_axis=None,
+                       fsdp_axis=0),
+        "wkv_a": pf.dense("wkv_a", (L, d, r + dr), tp_axis=None,
+                          fsdp_axis=0),
+        "kv_norm": pf.ones("kv_norm", (L, r)),
+        "wk_b": pf.dense("wk_b", (L, r, nq * dn), tp_axis=None, fsdp_axis=0),
+        "wv_b": pf.dense("wv_b", (L, r, nq * dv), tp_axis=None, fsdp_axis=0),
+        "wo": pf.dense("wo", (L, nq * dv, d), tp_axis=None, fsdp_axis=1),
+    }
+
+
+def mla_attention_op(x, p, cfg: ModelConfig, comm: Comm, *, q_offset
+                     ) -> jax.Array:
+    """Latent attention in its full (expanded) form, for training and
+    prefill.  x (s_local, b, d) pre-normed; queries for the local rows,
+    the latent and rope key gathered over the sequence (Plan B's
+    schedule: the gathered row is r + rope wide)."""
+    nq, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    s_l, b, _ = x.shape
+    q = jnp.tensordot(x, comm.weight(p["wq"], fsdp_axis=0), axes=1)
+    q = q.reshape(s_l, b, nq, dn + dr)
+    kv_a = comm.ag_seq(jnp.tensordot(
+        x, comm.weight(p["wkv_a"], fsdp_axis=0), axes=1))  # (s, b, r+dr)
+    s = kv_a.shape[0]
+    c = rms_norm(kv_a[..., :r], p["kv_norm"], eps=MLA_KV_NORM_EPS)
+    k_pe = apply_rope_pairs(kv_a[..., None, r:],
+                            jnp.arange(s, dtype=jnp.int32), cfg.rope_theta)
+    k_nope = jnp.tensordot(c, comm.weight(p["wk_b"], fsdp_axis=0),
+                           axes=1).reshape(s, b, nq, dn)
+    v = jnp.tensordot(c, comm.weight(p["wv_b"], fsdp_axis=0),
+                      axes=1).reshape(s, b, nq, dv)
+    q_pe = apply_rope_pairs(q[..., dn:], q_offset + jnp.arange(
+        s_l, dtype=jnp.int32), cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (s, b, nq, dr))],
+                        axis=-1)
+    o = flash_attention(q, k, v, causal=True, q_offset=q_offset,
+                        scale=(dn + dr) ** -0.5)
+    return jnp.tensordot(o.reshape(s_l, b, nq * dv),
+                         comm.weight(p["wo"], fsdp_axis=1), axes=1)
+
+
 def _init_layer_stack(pf: ParamFactory, cfg: ModelConfig, L: int,
-                      *, causal_attn: bool = True) -> Dict[str, jax.Array]:
-    """One homogeneous stack of L layers for the config's family."""
+                      *, causal_attn: bool = True, dense: bool = False
+                      ) -> Dict[str, jax.Array]:
+    """One homogeneous stack of L layers for the config's family;
+    ``dense``: a leading dense layer of a MoE model (its FFN of width
+    ``dense_ff`` in place of the experts)."""
     p: Dict[str, jax.Array] = {}
     p.update(_init_norm(pf, cfg, "norm1", L))
-    if cfg.family in ("dense", "moe", "vlm", "audio", "hybrid"):
+    if cfg.is_mla:
+        p.update(init_mla(pf, cfg, L))
+    elif cfg.family in ("dense", "moe", "vlm", "audio", "hybrid"):
         p.update(init_attention(pf, cfg, stacked_layers=L))
     if cfg.family in ("ssm", "hybrid"):
         p.update(init_ssm(pf, cfg, stacked_layers=L))
@@ -55,7 +120,10 @@ def _init_layer_stack(pf: ParamFactory, cfg: ModelConfig, L: int,
                                   stacked=True)
         p["mix_norm_s"] = pf.ones("mix_norm_s", (L, cfg.d_model),
                                   stacked=True)
-    if cfg.family == "moe":
+    if dense:
+        p.update(_init_norm(pf, cfg, "norm2", L))
+        p.update(init_mlp(pf, cfg, stacked_layers=L, d_ff=cfg.dense_ff))
+    elif cfg.family == "moe":
         p.update(_init_norm(pf, cfg, "norm2", L))
         p.update(init_moe(pf, cfg, stacked_layers=L))
         if cfg.shared_expert_ff:
@@ -123,7 +191,10 @@ def init_params(cfg: ModelConfig, key: jax.Array
         dp.update(init_mlp(pf, cfg, stacked_layers=L))
         grab(dp, "layers")
     else:
-        grab(_init_layer_stack(pf, cfg, cfg.n_layers), "layers")
+        if cfg.first_dense_layers:
+            grab(_init_layer_stack(pf, cfg, cfg.first_dense_layers,
+                                   dense=True), "dense_layers")
+        grab(_init_layer_stack(pf, cfg, cfg.n_stacked_layers), "layers")
     return params, specs
 
 
@@ -149,10 +220,12 @@ def _mlp_op(x, lp, cfg, comm, prefix: str = "") -> jax.Array:
 
 
 def _decoder_block(x, lp, idx, cfg: ModelConfig, comm: Comm, plan: TPPlan,
-                   q_offset, memory=None) -> Tuple[jax.Array, Dict]:
-    """One decoder layer of any family; returns (x', aux)."""
+                   q_offset, memory=None, dense: bool = False
+                   ) -> Tuple[jax.Array, Dict]:
+    """One decoder layer of any family; returns (x', aux).  ``dense``: a
+    MoE model's leading dense layer."""
     aux: Dict[str, jax.Array] = {}
-    h = apply_norm(cfg.norm, x, lp.get("norm1"))
+    h = apply_norm(cfg.norm, x, lp.get("norm1"), cfg.norm_eps)
 
     if cfg.family == "ssm":
         return x + ssm_op(h, lp, cfg, comm, plan), aux
@@ -167,8 +240,11 @@ def _decoder_block(x, lp, idx, cfg: ModelConfig, comm: Comm, plan: TPPlan,
         h2 = apply_norm(cfg.norm, x, lp.get("norm2"))
         return x + _mlp_op(h2, lp, cfg, comm), aux
 
-    attn = swa_attention_op(h, lp, cfg, comm, plan, layer_idx=idx,
-                            q_offset=q_offset)
+    if cfg.is_mla:
+        attn = mla_attention_op(h, lp, cfg, comm, q_offset=q_offset)
+    else:
+        attn = swa_attention_op(h, lp, cfg, comm, plan, layer_idx=idx,
+                                q_offset=q_offset)
     if cfg.parallel_block:                       # Cohere: attn ∥ mlp
         return x + attn + _mlp_op(h, lp, cfg, comm), aux
 
@@ -177,8 +253,8 @@ def _decoder_block(x, lp, idx, cfg: ModelConfig, comm: Comm, plan: TPPlan,
         hx = rms_norm(x, lp["normx"])
         x = x + attention_op(hx, lp, cfg, comm, plan, window=0,
                              q_offset=q_offset, memory=memory, prefix="x_")
-    h2 = apply_norm(cfg.norm, x, lp.get("norm2"))
-    if cfg.family == "moe":
+    h2 = apply_norm(cfg.norm, x, lp.get("norm2"), cfg.norm_eps)
+    if cfg.family == "moe" and not dense:
         moe_out, aux = moe_block(h2, lp, cfg, comm)
         if cfg.shared_expert_ff:
             moe_out = moe_out + _mlp_op(h2, lp, cfg, comm, prefix="shared_")
@@ -269,17 +345,22 @@ def forward(params: Dict[str, Any], batch: Dict[str, jax.Array],
             remat=remat, length=n_cross)
     else:
         mem = memory
+        for i in range(cfg.first_dense_layers):
+            lp = jax.tree_util.tree_map(lambda a: a[i],
+                                        params["dense_layers"])
+            x, _ = _decoder_block(x, lp, i, cfg, comm, plan, q_offset,
+                                  dense=True)
 
         def body(xc, lp, idx):
-            return _decoder_block(xc, lp, idx, cfg, comm, plan, q_offset,
-                                  memory=mem)
+            return _decoder_block(xc, lp, idx + cfg.first_dense_layers, cfg,
+                                  comm, plan, q_offset, memory=mem)
 
         x, aux = _scan_stack(x, params["layers"], cfg, comm, plan,
                              q_offset, body=body, remat=remat,
-                             length=cfg.n_layers)
+                             length=cfg.n_stacked_layers)
 
     x = apply_norm("rmsnorm" if cfg.norm == "rmsnorm" else "layernorm",
-                   x, params["final_norm"])
+                   x, params["final_norm"], cfg.norm_eps)
     x = comm.ag_seq(x)                              # full seq for the head
     n_layers = max(cfg.n_layers, 1)
     # aux terms (router losses) are computed from *local* tokens, so they

@@ -8,11 +8,24 @@ Cache layout (global view; local view divides by the mesh):
     ssm_state (L, B, H, N, P)        heads over ``model``, batch over ``data``
     conv_tail (L, K-1, B, d_inner)   channels with the heads
     cross_k/v (L, T, B, n_kv, dh)    (enc-dec / VLM) precomputed memory KV
+    latent   (L, B, S, W)            (latent attention) the normed kv
+                                     latent and the rotary key (r + r_pe,
+                                     zero-padded to W, a multiple of 128
+                                     lanes), in place of K/V; seq-sharded
+                                     like them; rows major, as the
+                                     batched attention products read it
+    expert_load (3, L_moe, E_held)   (MoE) per held expert, summed over
+                                     steps: tokens routed, most routed in
+                                     one step, tokens combined
 
 K/V are stored in the order the layer scan's loop keeps its carry in, so
 XLA transposes nothing at the loop's edges; each step writes one
 ``(n_kv, B, dh)`` row per layer in place and attention reads the layer
-where it lies.
+where it lies.  The latent cache is not carried: the loop only reads it,
+each layer attends to its new row beside the cached ones, and the step
+writes the rows of all layers in place after the loop (carried and
+written per layer, XLA lays it out for the row write and copies each
+layer's slice into the order the attention products read).
 
 Decode dataflow per layer (the LCI reading: every KV shard is a *channel*;
 partial attention results are joined by a synchronizer — implemented as
@@ -50,13 +63,16 @@ import jax.numpy as jnp
 from jax.lax import axis_size
 
 from repro.distributed.comm import Comm, _axes, local_comm
-from repro.models.attention import (combine_decode_partials, decode_attention)
+from repro.models.attention import (NEG_INF, combine_decode_partials,
+                                    decode_attention)
 from repro.models.blocks import TPPlan, layer_window, tp_plan
 from repro.models.common import ModelConfig, shard_decisions
-from repro.models.layers import (apply_norm, apply_rope, greedy_sample,
-                                 lm_head_logits, mlp_activation, rms_norm)
-from repro.models.moe import moe_block
+from repro.models.layers import (apply_norm, apply_rope, apply_rope_pairs,
+                                 greedy_sample, lm_head_logits,
+                                 mlp_activation, rms_norm)
+from repro.models.moe import moe_decode
 from repro.models.ssm import ssd_decode_step
+from repro.serving.kv_cache import latent_width
 from repro.models import lm as lm_mod
 
 
@@ -75,21 +91,28 @@ class DecodeCache:
     cross_k: Optional[jax.Array] = None      # (L, T, b, n_kv, dh)
     cross_v: Optional[jax.Array] = None
     length: Optional[jax.Array] = None       # () int32 — #valid positions
+    latent: Optional[jax.Array] = None       # (L, b, S_loc, W)
+    expert_load: Optional[jax.Array] = None  # (3, L_moe, E_held) int32
 
 
 jax.tree_util.register_pytree_node(
     DecodeCache,
     lambda c: ((c.k, c.v, c.ssm_state, c.conv_tail, c.cross_k, c.cross_v,
-                c.length), None),
+                c.length, c.latent, c.expert_load), None),
     lambda _, xs: DecodeCache(*xs))
 
 #: ``jax.named_scope`` of the layer scan's per-layer cache reads and
 #: writes: device-trace readers find the cache traffic by this name
 CACHE_IO = "cache_io"
+#: ``jax.named_scope`` of latent attention's decode: its projections, the
+#: query absorption, the attention over the latent rows and the output
+#: absorption (the latent row's write is under ``CACHE_IO``)
+MLA_ATTN = "mla_attn"
 
 
 def _has_attn(cfg: ModelConfig) -> bool:
-    return cfg.family != "ssm"
+    """Per-head K/V in the cache."""
+    return cfg.family != "ssm" and not cfg.is_mla
 
 
 def _has_ssm(cfg: ModelConfig) -> bool:
@@ -122,6 +145,12 @@ def init_cache(cfg: ModelConfig, seq_len: int, batch: int, *,
         c.conv_tail = jnp.zeros(
             (cfg.n_layers, cfg.ssm_conv_kernel - 1, batch, cfg.ssm_d_inner),
             cfg.dtype)
+    if cfg.is_mla:
+        c.latent = jnp.zeros((L, batch, seq_len, latent_width(cfg)),
+                             cfg.dtype)
+    if cfg.family == "moe":
+        c.expert_load = jnp.zeros(
+            (3, cfg.n_stacked_layers, cfg.n_experts_held), jnp.int32)
     nx = _n_cross(cfg)
     if nx and n_memory:
         xshape = (nx, n_memory, batch, cfg.n_kv_heads,
@@ -155,6 +184,8 @@ def cache_pspecs(cfg: ModelConfig, *, batch: int, model_axis="model",
         cross_v=(P(None, None, batch_spec, None, None) if _n_cross(cfg)
                  else None),
         length=P(),
+        latent=P(None, batch_spec, seq_axes, None) if cfg.is_mla else None,
+        expert_load=P() if cfg.family == "moe" else None,
     )
 
 
@@ -374,6 +405,95 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
     return out, k_all, v_all
 
 
+def _decode_mla(x, lp, cfg: ModelConfig, comm: Comm, lat_all, idx, pos, *,
+                joint_kv: bool):
+    """Latent attention for one token per row, in the absorbed form.
+
+    x (b, d) pre-normed, replicated over model; ``lat_all`` (L, b, S_loc,
+    W) the local seq shard of the latent cache, of which this layer is
+    ``idx``, rows below ``pos`` written.  The query's nope part is taken
+    through ``wk_b`` into the latent space, so a score is one dot with a
+    cached row [latent, rotary key]; the attention's output stays in the
+    latent space until ``wv_b`` takes it to the heads.  K and V are never
+    expanded.  The new token's own row is attended beside the cached
+    ones.  Returns (out (b, d), row (b, W) for position ``pos``, in the
+    cache's dtype)."""
+    nq, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    f32 = jnp.float32
+    _, b, shard_len, width = lat_all.shape
+    posv = jnp.full((1,), pos, jnp.int32)
+    with jax.named_scope(MLA_ATTN):
+        q = jnp.tensordot(x, comm.weight(lp["wq"], fsdp_axis=0),
+                          axes=1).reshape(b, nq, dn + dr)
+        kv_a = jnp.tensordot(x, comm.weight(lp["wkv_a"], fsdp_axis=0),
+                             axes=1)                       # (b, r + r_pe)
+        c = rms_norm(kv_a[:, :r], lp["kv_norm"], eps=lm_mod.MLA_KV_NORM_EPS)
+        k_pe = apply_rope_pairs(kv_a[None, :, None, r:], posv,
+                                cfg.rope_theta)[0, :, 0]
+        q_pe = apply_rope_pairs(q[None, ..., dn:], posv, cfg.rope_theta)[0]
+        w_uk = comm.weight(lp["wk_b"], fsdp_axis=0).reshape(r, nq, dn)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :dn], w_uk,
+                           preferred_element_type=f32)
+        pad = width - r - dr
+        qc = (jnp.concatenate([q_lat, q_pe.astype(f32),
+                               jnp.zeros((b, nq, pad), f32)], axis=-1)
+              * (dn + dr) ** -0.5)                        # (b, nq, W)
+        row = jnp.concatenate([c, k_pe, jnp.zeros((b, pad), c.dtype)],
+                              axis=-1).astype(lat_all.dtype)
+    # the layer read twice, whole for the scores and its latent part for
+    # the values: one slice feeding both products is copied out first
+    with jax.named_scope(CACHE_IO):
+        keys = jax.lax.dynamic_slice(lat_all, (idx, 0, 0, 0),
+                                     (1, b, shard_len, width))[0]
+        vals = jax.lax.dynamic_slice(lat_all, (idx, 0, 0, 0),
+                                     (1, b, shard_len, r))[0]
+
+    with jax.named_scope(MLA_ATTN):
+        axes = _kv_axes(comm, joint=joint_kv)
+        my_start = _axes_index(comm, axes) * shard_len
+        rel = pos - my_start
+        owns = (rel >= 0) & (rel < shard_len)
+        # cached rows below pos, then the new row (on the shard that owns
+        # its position)
+        valid = my_start + jnp.arange(shard_len, dtype=jnp.int32) < pos
+        sc = jnp.einsum("bhc,bsc->bhs", qc, keys.astype(f32))
+        sc = jnp.where(valid[None, None, :], sc, NEG_INF)
+        s_new = jnp.einsum("bhc,bc->bh", qc, row.astype(f32))
+        s_new = jnp.where(owns, s_new, NEG_INF)
+        m = jnp.maximum(sc.max(axis=-1), s_new)
+        p = jnp.exp(sc - m[..., None])
+        p_new = jnp.exp(s_new - m)
+        num = jnp.einsum("bhs,bsr->bhr", p, vals.astype(f32))
+        num = num + p_new[..., None] * row[:, None, :r].astype(f32)
+        m_g = _pmax_axes(m, axes)
+        corr = jnp.exp(m - m_g)
+        l_g = _psum_axes((p.sum(axis=-1) + p_new) * corr, axes)
+        num_g = _psum_axes(num * corr[..., None], axes)
+        o_lat = num_g / jnp.maximum(l_g, 1e-37)[..., None]  # (b, nq, r)
+        w_uv = comm.weight(lp["wv_b"], fsdp_axis=0).reshape(r, nq, dv)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(x.dtype), w_uv,
+                       preferred_element_type=f32)
+        out = jnp.tensordot(o.reshape(b, nq * dv).astype(x.dtype),
+                            comm.weight(lp["wo"], fsdp_axis=1), axes=1)
+    return out, row
+
+
+def _write_latent_rows(lat_all, rows, pos, comm: Comm, *, joint_kv: bool):
+    """Every layer's row ``rows`` (L, b, W) into the latent cache (L, b,
+    S_loc, W) at position ``pos``, in place, on the shard that owns it."""
+    shard_len = lat_all.shape[2]
+    axes = _kv_axes(comm, joint=joint_kv)
+    rel = pos - _axes_index(comm, axes) * shard_len
+    owns = (rel >= 0) & (rel < shard_len)
+    start = (0, 0, jnp.clip(rel, 0, shard_len - 1), 0)
+    with jax.named_scope(CACHE_IO):
+        old = jax.lax.dynamic_slice(lat_all, start, rows.shape[:2] + (1,)
+                                    + rows.shape[2:])
+        new = jnp.where(owns, rows[:, :, None], old)
+        return jax.lax.dynamic_update_slice(lat_all, new, start)
+
+
 def _decode_mlp(x, lp, cfg, comm: Comm, prefix: str = "",
                 tp2d: bool = False, defer_out: bool = False) -> jax.Array:
     if cfg.mlp in ("swiglu", "geglu"):
@@ -478,13 +598,17 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
 
         is_vlm = cfg.family == "vlm"
         n_cross = _n_cross(cfg)
+        lat = cache.latent
         per = (cfg.cross_attn_every - 1) if is_vlm else 0
 
-        def layer(carry, scanned):
-            xc, kall, vall, sall, call_ = carry
+        def layer(carry, scanned, dense=False):
+            # ``dense``: a MoE model's leading dense layer, run before
+            # the scan
+            xc, kall, vall, sall, call_, load = carry
             idx, lp = scanned["idx"], scanned["lp"]
+            row = None
             aux_kv = scanned.get("xlp")
-            h = apply_norm(cfg.norm, xc, lp.get("norm1"))
+            h = apply_norm(cfg.norm, xc, lp.get("norm1"), cfg.norm_eps)
             window = layer_window(cfg, idx) if cfg.sliding_window else 0
 
             with jax.named_scope(CACHE_IO):
@@ -507,10 +631,14 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 h2 = apply_norm(cfg.norm, xc, lp.get("norm2"))
                 xc = xc + _decode_mlp(h2, lp, cfg, comm, tp2d=tp2d)
             else:
-                a_out, kall, vall = _decode_attn_layer(
-                    h, lp, cfg, comm, plan, kall, vall, idx, pos, window,
-                    joint_kv=joint_kv, tp2d=tp2d,
-                    defer_out=tp2d and cfg.parallel_block)
+                if cfg.is_mla:
+                    a_out, row = _decode_mla(h, lp, cfg, comm, cache.latent,
+                                             idx, pos, joint_kv=joint_kv)
+                else:
+                    a_out, kall, vall = _decode_attn_layer(
+                        h, lp, cfg, comm, plan, kall, vall, idx, pos, window,
+                        joint_kv=joint_kv, tp2d=tp2d,
+                        defer_out=tp2d and cfg.parallel_block)
                 if cfg.parallel_block:
                     # §Perf iteration 3: under tp2d, attention and MLP
                     # write the SAME residual; add their pre-reduction
@@ -532,17 +660,11 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                             joint_kv=joint_kv, prefix="x_",
                             memory_kv=aux_kv, tp2d=tp2d)
                         xc = xc + x_out
-                    h2 = apply_norm(cfg.norm, xc, lp.get("norm2"))
-                    if cfg.family == "moe":
-                        # MoE experts keep the gather path (dispatch owns
-                        # the a2a); router/shared-mlp ride tp2d
-                        mo, _ = moe_block(h2[None], lp, cfg, comm)
-                        mo = mo[0]
-                        if cfg.shared_expert_ff:
-                            mo = mo + _decode_mlp(h2, lp, cfg, comm,
-                                                  prefix="shared_",
-                                                  tp2d=tp2d)
-                        xc = xc + mo
+                    h2 = apply_norm(cfg.norm, xc, lp.get("norm2"),
+                                    cfg.norm_eps)
+                    if cfg.family == "moe" and not dense:
+                        xc, load = _decode_moe(xc, h2, lp, load,
+                                               idx - cfg.first_dense_layers)
                     else:
                         xc = xc + _decode_mlp(h2, lp, cfg, comm,
                                               tp2d=tp2d)
@@ -551,12 +673,39 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 if sall is not None and st is not None:
                     sall = sall.at[idx].set(st)
                     call_ = call_.at[idx].set(ct)
-            return (xc, kall, vall, sall, call_), ()
+            return (xc, kall, vall, sall, call_, load), row
 
-        L_self = cfg.n_layers - n_cross if is_vlm else cfg.n_layers
-        scanned = {"idx": jnp.arange(L_self, dtype=jnp.int32),
-                   "lp": params["layers"]}
-        carry = (x, cache.k, cache.v, cache.ssm_state, cache.conv_tail)
+        def _decode_moe(xc, h2, lp, load, j):
+            """Dropless routed experts (the gather path for their weights)
+            plus the shared expert (rides tp2d); the held experts' load
+            is added to row ``j`` of the counter."""
+            mo, n = moe_decode(h2, lp, cfg, comm)
+            if cfg.shared_expert_ff:
+                mo = mo + _decode_mlp(h2, lp, cfg, comm, prefix="shared_",
+                                      tp2d=tp2d)
+            load = load.at[0, j].add(n[0]).at[1, j].max(n[0]) \
+                .at[2, j].add(n[1])
+            return xc + mo, load
+
+        carry = (x, cache.k, cache.v, cache.ssm_state, cache.conv_tail,
+                 cache.expert_load)
+        dense_rows = []
+        for i in range(cfg.first_dense_layers):
+            # a constant index makes XLA copy the layer's latent slice
+            # out (a whole layer a step); through a barrier it is read in
+            # place, as in the loop
+            carry, row = layer(carry, {
+                "idx": jax.lax.optimization_barrier(jnp.int32(i)),
+                "lp": jax.tree_util.tree_map(lambda a: a[i],
+                                             params["dense_layers"])},
+                dense=True)
+            dense_rows.append(row)
+        L_self = (cfg.n_layers - n_cross if is_vlm
+                  else cfg.n_stacked_layers)
+        idxs = jnp.arange(L_self, dtype=jnp.int32)
+        if cfg.first_dense_layers:
+            idxs = idxs + cfg.first_dense_layers
+        scanned = {"idx": idxs, "lp": params["layers"]}
         if cfg.is_encdec:
             def layer_encdec(c, sl):
                 idx, lp, xk, xv = sl
@@ -602,12 +751,17 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
             def layer_plain(c, sl):
                 idx, lp = sl
                 return layer(c, {"idx": idx, "lp": lp})
-            carry, _ = jax.lax.scan(layer_plain, carry,
-                                    (scanned["idx"], params["layers"]))
+            carry, rows = jax.lax.scan(layer_plain, carry,
+                                       (scanned["idx"], params["layers"]))
+            if cfg.is_mla:
+                lat = _write_latent_rows(
+                    cache.latent, jnp.concatenate(
+                        [jnp.stack(dense_rows), rows]) if dense_rows
+                    else rows, pos, comm, joint_kv=joint_kv)
 
-        xc, kall, vall, sall, call_ = carry
+        xc, kall, vall, sall, call_, load = carry
         xc = apply_norm("rmsnorm" if cfg.norm == "rmsnorm" else "layernorm",
-                        xc, params["final_norm"])
+                        xc, params["final_norm"], cfg.norm_eps)
         head = params.get("lm_head", params["emb"])
         if tp2d:
             # head columns (d) stay data-sharded: slice x, partial logits,
@@ -628,7 +782,8 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
         next_tokens = greedy_sample(logits, comm)
         new_cache = DecodeCache(k=kall, v=vall, ssm_state=sall,
                                 conv_tail=call_, cross_k=cache.cross_k,
-                                cross_v=cache.cross_v, length=pos + 1)
+                                cross_v=cache.cross_v, length=pos + 1,
+                                latent=lat, expert_load=load)
         return next_tokens, new_cache
 
     return serve_step

@@ -79,3 +79,26 @@ class PagedKVAllocator:
     @property
     def free_pages(self) -> int:
         return self.pool.free_packets()
+
+
+def latent_width(cfg) -> int:
+    """A cached latent row's width: the kv latent and the rotary key,
+    zero-padded to whole 128-lane tiles.  A minor dim off the tile (576)
+    is padded on the chip all the same, and makes XLA lay the cache out
+    with another dim minor, which the attention products then copy."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def token_cache_bytes(cfg) -> int:
+    """Device bytes one cached position of one request takes over all
+    layers, what a page of the allocator holds per position: K and V of
+    every kv head, or, under latent attention, the normed latent and the
+    rotary key as :func:`latent_width` pads them (no per-head K/V;
+    Moonlight's 512 + 64 values take 640, 1,280 B a layer in bf16)."""
+    import numpy as np
+    item = np.dtype(cfg.dtype).itemsize
+    if cfg.is_mla:
+        width = latent_width(cfg)
+    else:
+        width = 2 * cfg.n_kv_heads * cfg.resolved_head_dim
+    return cfg.n_layers * width * item

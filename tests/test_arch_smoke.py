@@ -67,7 +67,7 @@ def test_full_configs_match_assignment():
         "olmo-1b": (16, 2048, 16, 16, 8192, 50304),
         "gemma3-1b": (26, 1152, 4, 1, 6912, 262144),
         "minitron-8b": (32, 4096, 32, 8, 16384, 256000),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonshot-v1-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
         "olmoe-1b-7b": (16, 2048, 16, 16, 1024, 50304),
         "llama-3.2-vision-90b": (100, 8192, 64, 8, 28672, 128256),
         "mamba2-370m": (48, 1024, 0, 0, 0, 50280),
@@ -109,9 +109,9 @@ def test_param_counts_sane():
     assert 90e9 < get_config("command-r-plus-104b").param_count() < 120e9
     assert 0.9e9 < get_config("olmo-1b").param_count() < 1.6e9
     assert 75e9 < get_config("llama-3.2-vision-90b").param_count() < 105e9
-    # the assignment's dims (48L x 64e x d_ff 1408) give ~29B total / ~5B
-    # active — we implement the assignment verbatim, not the HF card
+    # Moonlight-16B-A3B as published (MLA, a dense first layer, 26 MoE
+    # layers of 64 experts top 6 with 2 shared): ~16B total, ~3B active
     moe = get_config("moonshot-v1-16b-a3b")
-    assert 20e9 < moe.param_count() < 35e9
-    assert 2e9 < moe.active_param_count() < 6e9
+    assert 15e9 < moe.param_count() < 17e9
+    assert 2.5e9 < moe.active_param_count() < 3.5e9
     assert 0.3e9 < get_config("mamba2-370m").param_count() < 0.6e9
