@@ -179,6 +179,12 @@ def count_costs(jaxpr, axis_sizes: Dict[str, int],
                         mult * float(p.get("length", 1)))
             continue
 
+        if name == "pallas_call":
+            # the kernel body runs once per grid step
+            count_costs(p["jaxpr"], axis_sizes, c,
+                        mult * math.prod(p["grid_mapping"].grid))
+            continue
+
         if name == "while":
             c.unknown_while += 1
             for sub in _sub_jaxprs(p):

@@ -17,6 +17,9 @@ Cache layout (global view; local view divides by the mesh):
     expert_load (3, L_moe, E_held)   (MoE) per held expert, summed over
                                      steps: tokens routed, most routed in
                                      one step, tokens combined
+    latent_blocks ()                 (latent attention) row blocks of the
+                                     latent cache its attention read,
+                                     summed over layers, shards and steps
 
 K/V are stored in the order the layer scan's loop keeps its carry in, so
 XLA transposes nothing at the loop's edges; each step writes one
@@ -25,7 +28,11 @@ where it lies.  The latent cache is not carried: the loop only reads it,
 each layer attends to its new row beside the cached ones, and the step
 writes the rows of all layers in place after the loop (carried and
 written per layer, XLA lays it out for the row write and copies each
-layer's slice into the order the attention products read).
+layer's slice into the order the attention products read).  Latent
+attention reads a layer's cached rows inside one kernel
+(``kernels/latent_decode``): each block of rows that holds a live row
+once, for both the scores and the values, and the blocks past the live
+front not at all.
 
 Decode dataflow per layer (the LCI reading: every KV shard is a *channel*;
 partial attention results are joined by a synchronizer — implemented as
@@ -93,12 +100,13 @@ class DecodeCache:
     length: Optional[jax.Array] = None       # () int32 — #valid positions
     latent: Optional[jax.Array] = None       # (L, b, S_loc, W)
     expert_load: Optional[jax.Array] = None  # (3, L_moe, E_held) int32
+    latent_blocks: Optional[jax.Array] = None  # () int32
 
 
 jax.tree_util.register_pytree_node(
     DecodeCache,
     lambda c: ((c.k, c.v, c.ssm_state, c.conv_tail, c.cross_k, c.cross_v,
-                c.length, c.latent, c.expert_load), None),
+                c.length, c.latent, c.expert_load, c.latent_blocks), None),
     lambda _, xs: DecodeCache(*xs))
 
 #: ``jax.named_scope`` of the layer scan's per-layer cache reads and
@@ -148,6 +156,7 @@ def init_cache(cfg: ModelConfig, seq_len: int, batch: int, *,
     if cfg.is_mla:
         c.latent = jnp.zeros((L, batch, seq_len, latent_width(cfg)),
                              cfg.dtype)
+        c.latent_blocks = jnp.zeros((), jnp.int32)
     if cfg.family == "moe":
         c.expert_load = jnp.zeros(
             (3, cfg.n_stacked_layers, cfg.n_experts_held), jnp.int32)
@@ -186,6 +195,7 @@ def cache_pspecs(cfg: ModelConfig, *, batch: int, model_axis="model",
         length=P(),
         latent=P(None, batch_spec, seq_axes, None) if cfg.is_mla else None,
         expert_load=P() if cfg.family == "moe" else None,
+        latent_blocks=P() if cfg.is_mla else None,
     )
 
 
@@ -441,34 +451,27 @@ def _decode_mla(x, lp, cfg: ModelConfig, comm: Comm, lat_all, idx, pos, *,
               * (dn + dr) ** -0.5)                        # (b, nq, W)
         row = jnp.concatenate([c, k_pe, jnp.zeros((b, pad), c.dtype)],
                               axis=-1).astype(lat_all.dtype)
-    # the layer read twice, whole for the scores and its latent part for
-    # the values: one slice feeding both products is copied out first
-    with jax.named_scope(CACHE_IO):
-        keys = jax.lax.dynamic_slice(lat_all, (idx, 0, 0, 0),
-                                     (1, b, shard_len, width))[0]
-        vals = jax.lax.dynamic_slice(lat_all, (idx, 0, 0, 0),
-                                     (1, b, shard_len, r))[0]
-
-    with jax.named_scope(MLA_ATTN):
-        axes = _kv_axes(comm, joint=joint_kv)
-        my_start = _axes_index(comm, axes) * shard_len
-        rel = pos - my_start
+        # the cached rows below pos, each live block of the layer read once
+        # for both products where it lies, the dead ones not at all; then
+        # the new row (on the shard that owns its position).  Imported
+        # here: Pallas takes over a second to import, and only latent
+        # attention runs a kernel
+        from repro.kernels.latent_decode.ops import latent_decode
+        axes, rel = _latent_shard(pos, shard_len, comm, joint_kv=joint_kv)
         owns = (rel >= 0) & (rel < shard_len)
-        # cached rows below pos, then the new row (on the shard that owns
-        # its position)
-        valid = my_start + jnp.arange(shard_len, dtype=jnp.int32) < pos
-        sc = jnp.einsum("bhc,bsc->bhs", qc, keys.astype(f32))
-        sc = jnp.where(valid[None, None, :], sc, NEG_INF)
+        num, m, l = latent_decode(qc, lat_all, idx,
+                                  jnp.clip(rel, 0, shard_len), rank=r)
         s_new = jnp.einsum("bhc,bc->bh", qc, row.astype(f32))
         s_new = jnp.where(owns, s_new, NEG_INF)
-        m = jnp.maximum(sc.max(axis=-1), s_new)
-        p = jnp.exp(sc - m[..., None])
-        p_new = jnp.exp(s_new - m)
-        num = jnp.einsum("bhs,bsr->bhr", p, vals.astype(f32))
-        num = num + p_new[..., None] * row[:, None, :r].astype(f32)
-        m_g = _pmax_axes(m, axes)
-        corr = jnp.exp(m - m_g)
-        l_g = _psum_axes((p.sum(axis=-1) + p_new) * corr, axes)
+        m_new = jnp.maximum(m, s_new)
+        corr = jnp.exp(m - m_new)
+        p_new = jnp.exp(s_new - m_new)
+        num = (num * corr[..., None]
+               + p_new[..., None] * row[:, None, :r].astype(f32))
+        l = l * corr + p_new
+        m_g = _pmax_axes(m_new, axes)
+        corr = jnp.exp(m_new - m_g)
+        l_g = _psum_axes(l * corr, axes)
         num_g = _psum_axes(num * corr[..., None], axes)
         o_lat = num_g / jnp.maximum(l_g, 1e-37)[..., None]  # (b, nq, r)
         w_uv = comm.weight(lp["wv_b"], fsdp_axis=0).reshape(r, nq, dv)
@@ -479,12 +482,18 @@ def _decode_mla(x, lp, cfg: ModelConfig, comm: Comm, lat_all, idx, pos, *,
     return out, row
 
 
+def _latent_shard(pos, shard_len: int, comm: Comm, *, joint_kv: bool):
+    """(the axes the latent cache's rows are sharded over, ``pos`` less
+    this shard's first row)."""
+    axes = _kv_axes(comm, joint=joint_kv)
+    return axes, pos - _axes_index(comm, axes) * shard_len
+
+
 def _write_latent_rows(lat_all, rows, pos, comm: Comm, *, joint_kv: bool):
     """Every layer's row ``rows`` (L, b, W) into the latent cache (L, b,
     S_loc, W) at position ``pos``, in place, on the shard that owns it."""
     shard_len = lat_all.shape[2]
-    axes = _kv_axes(comm, joint=joint_kv)
-    rel = pos - _axes_index(comm, axes) * shard_len
+    _, rel = _latent_shard(pos, shard_len, comm, joint_kv=joint_kv)
     owns = (rel >= 0) & (rel < shard_len)
     start = (0, 0, jnp.clip(rel, 0, shard_len - 1), 0)
     with jax.named_scope(CACHE_IO):
@@ -780,10 +789,20 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
             logits = lm_head_logits(xc, head_full, comm,
                                     real_vocab=cfg.vocab)
         next_tokens = greedy_sample(logits, comm)
+        blocks = cache.latent_blocks
+        if cfg.is_mla:
+            # every layer reads the same live blocks of its rows
+            from repro.kernels.latent_decode.ops import blocks_read
+            shard_len = cache.latent.shape[2]
+            axes, rel = _latent_shard(pos, shard_len, comm,
+                                      joint_kv=joint_kv)
+            blocks = blocks + cache.latent.shape[0] * _psum_axes(
+                blocks_read(jnp.clip(rel, 0, shard_len), shard_len), axes)
         new_cache = DecodeCache(k=kall, v=vall, ssm_state=sall,
                                 conv_tail=call_, cross_k=cache.cross_k,
                                 cross_v=cache.cross_v, length=pos + 1,
-                                latent=lat, expert_load=load)
+                                latent=lat, expert_load=load,
+                                latent_blocks=blocks)
         return next_tokens, new_cache
 
     return serve_step

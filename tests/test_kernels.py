@@ -6,6 +6,9 @@ import pytest
 
 from repro.kernels.flash_attention.kernel import flash_attention_tpu
 from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.kernels.latent_decode.kernel import NEG_INF as LATENT_NEG_INF
+from repro.kernels.latent_decode.kernel import latent_decode_tpu
+from repro.kernels.latent_decode.ref import latent_decode_ref
 from repro.kernels.rmsnorm.kernel import rmsnorm_tpu
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
 from repro.kernels.ssd_scan.kernel import ssd_scan_tpu
@@ -109,3 +112,75 @@ def test_flash_matches_model_attention():
                             block_q=16, block_k=16,
                             interpret=True).transpose(2, 0, 1, 3)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def _latent_inputs(dtype, b=16, seq=512, width=256, nq=4, layers=2):
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    qc = jax.random.normal(ks[0], (b, nq, width), jnp.float32) * 0.1
+    lat = jax.random.normal(ks[1], (layers, b, seq, width)).astype(dtype)
+    return qc, lat
+
+
+def _latent_tol(dtype):
+    # the kernel's products take the query and the probabilities in the
+    # cache's dtype, the oracle keeps them in f32
+    return 3e-2 if dtype == jnp.bfloat16 else 1e-5
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("block,batch_tile", [(128, 8), (64, 16)])
+@pytest.mark.parametrize("live", [0, 1, 127, 128, 129, 300, 511, 512])
+def test_latent_decode_sweep(live, block, batch_tile, dtype):
+    qc, lat = _latent_inputs(dtype)
+    got = latent_decode_tpu(qc, lat, 1, live, rank=128, block=block,
+                            batch_tile=batch_tile, interpret=True)
+    want = latent_decode_ref(qc, lat, 1, live, rank=128)
+    tol = _latent_tol(dtype)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=tol,
+                                   rtol=tol)
+    if live == 0:
+        assert (np.asarray(got[1]) == LATENT_NEG_INF).all()
+        assert not np.asarray(got[0]).any() and not np.asarray(got[2]).any()
+
+
+@pytest.mark.parametrize("pos", [200, 300])
+def test_latent_decode_seq_shards_combine(pos):
+    """Four 128-row shards, each given its own live count: the wholly dead
+    shards read nothing and drop out of the flash combine, which gives
+    what one read of the whole cache gives."""
+    qc, lat = _latent_inputs(jnp.float32)
+    parts = [latent_decode_tpu(qc, lat[:, :, i * 128:(i + 1) * 128], 1,
+                               min(max(pos - i * 128, 0), 128), rank=128,
+                               block=64, batch_tile=8, interpret=True)
+             for i in range(4)]
+    dead = [i for i in range(4) if i * 128 >= pos]
+    assert dead and pos % 128          # one shard partly live, some dead
+    for i in dead:
+        assert not np.asarray(parts[i][2]).any()
+    m_g = np.max([np.asarray(m) for _, m, _ in parts], axis=0)
+    num = sum(np.asarray(n) * np.exp(np.asarray(m) - m_g)[..., None]
+              for n, m, _ in parts)
+    den = sum(np.asarray(l) * np.exp(np.asarray(m) - m_g)
+              for _, m, l in parts)
+    wn, wm, wl = latent_decode_ref(qc, lat, 1, pos, rank=128)
+    np.testing.assert_allclose(num / den[..., None],
+                               np.asarray(wn / wl[..., None]), atol=1e-5,
+                               rtol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("live", [1, 129, 300])
+def test_latent_decode_ignores_dead_rows(live, dtype):
+    """Rows at and past ``live`` hold NaN: the output is finite and the
+    same as with those rows zero."""
+    qc, lat = _latent_inputs(dtype)
+    dead = (jnp.arange(lat.shape[2]) >= live)[None, None, :, None]
+    zero = latent_decode_tpu(qc, jnp.where(dead, 0, lat), 1, live, rank=128,
+                             block=128, batch_tile=8, interpret=True)
+    nan = latent_decode_tpu(qc, jnp.where(dead, jnp.nan, lat), 1, live,
+                            rank=128, block=128, batch_tile=8,
+                            interpret=True)
+    for z, n in zip(zero, nan):
+        assert np.isfinite(np.asarray(n)).all()
+        np.testing.assert_array_equal(np.asarray(n), np.asarray(z))

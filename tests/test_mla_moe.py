@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs import get_config, get_smoke
 from repro.distributed.comm import local_comm
+from repro.kernels.latent_decode.kernel import block_rows
 from repro.models import lm
 from repro.models.layers import greedy_sample, lm_head_logits
 from repro.models.moe import moe_block, moe_decode, router_topk
@@ -234,3 +235,27 @@ def test_cost_walker_counts_mla_dense_and_shared():
     jaxpr = jax.make_jaxpr(make_serve_step(cfg))(
         params, cache, jax.ShapeDtypeStruct((b,), jnp.int32))
     assert count_costs(jaxpr, {}).flops == 2 * b * per_row
+
+
+def test_latent_blocks_counts_the_live_blocks_read():
+    """From position 0, each step's attention reads, in every layer, the
+    blocks that hold a row below the position: none at first, a second
+    block once the position passes the first."""
+    cfg = get_smoke(ARCH)
+    seq, b, n = 256, 2, 140
+    block = block_rows(seq)
+    assert block < n - 1 < seq
+    step = jax.jit(make_serve_step(cfg))
+    params = _params(cfg)
+    cache = init_cache(cfg, seq, b)
+    toks = jnp.array([1, 2], jnp.int32)
+    for _ in range(n):
+        toks, cache = step(params, cache, toks)
+    L = cache.latent.shape[0]
+    assert int(cache.latent_blocks) == L * sum(-(-p // block)
+                                               for p in range(n))
+
+
+def test_latent_blocks_only_with_a_latent_cache():
+    assert init_cache(get_smoke(ARCH), 8, 2).latent_blocks is not None
+    assert init_cache(get_smoke("olmo-1b"), 8, 2).latent_blocks is None

@@ -1,6 +1,8 @@
-"""Compile the main path's kernels and the olmo-1b serve step for a
-described TPU v5e chip, at real widths; the serve step at the benchmark
-cell's shapes moves no whole K/V buffer.
+"""Compile the main path's kernels and the olmo-1b and Moonlight serve
+steps for a described TPU v5e chip, at real widths; at the benchmark
+cells' shapes the olmo-1b step moves no whole K/V buffer and the
+Moonlight step reads its latent cache only inside the latent decode
+kernel.
 
 Nothing runs: ``lower(...).compile()`` against a ``v5e:2x2`` topology
 raises what the chip's compiler would raise (tile-misaligned blocks,
@@ -10,6 +12,7 @@ one process at a time may load the TPU compiler library.
 """
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.doorbell.ops import stage_copy
 from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.latent_decode.kernel import latent_decode_tpu
 from repro.kernels.moe_gmm.kernel import moe_gmm_tpu
 from repro.kernels.rmsnorm.kernel import rmsnorm_tpu
 from repro.kernels.ssd_scan.kernel import ssd_scan_tpu
@@ -26,6 +30,7 @@ from repro.models.registry import build_model
 from repro.serving.engine import init_cache, make_serve_step
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _HEADER = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
 _INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
@@ -69,6 +74,17 @@ def test_flash_attention_olmo_1b(one_chip):
     q = _sds((1, cfg.n_heads, 2048, dh), jnp.bfloat16, one_chip)
     _compile_kernel(lambda q, k, v: flash_attention_tpu(q, k, v),
                     q, q, q)
+
+
+def test_latent_decode_moonlight_cell(one_chip):
+    """At the Moonlight cell's shapes: 27 layers x 256 rows x 512
+    positions x 640, bf16, and the f32 query of 16 heads."""
+    _compile_kernel(lambda q, lat, i, n: latent_decode_tpu(q, lat, i, n,
+                                                           rank=512),
+                    _sds((256, 16, 640), jnp.float32, one_chip),
+                    _sds((27, 256, 512, 640), jnp.bfloat16, one_chip),
+                    _sds((), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip))
 
 
 def test_rmsnorm_d2048(one_chip):
@@ -176,3 +192,42 @@ def test_olmo_1b_serve_step_writes_one_kv_row_in_place(one_chip):
                 writes.append(name)
             assert d != layer or op in _VIEWS, (name, op, root)
     assert len(writes) >= 2, writes          # K and V, in the layer loop
+
+
+def test_moonlight_serve_step_reads_latent_rows_in_the_kernel(one_chip):
+    """At the benchmark cell's shapes (256 rows, cache 512, donated) the
+    only op that reads the latent cache's rows is the latent decode
+    kernel, under the ``mla_attn`` scope that
+    ``moe_serve.mla_attn_ms_per_step`` reads; no op copies out a layer,
+    and only the rows' write gives a whole cache."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from benchmarks.chip.program_trace import in_scope, op_scopes
+    cfg = get_config("moonlight-16b-a3b-ep8")
+    params, _ = build_model(cfg).abstract_params()
+    cache = jax.eval_shape(lambda: init_cache(cfg, 512, 256))
+    on_chip = lambda t: jax.tree_util.tree_map(          # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    hlo = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        _sds((256,), jnp.int32, one_chip)).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2, calls        # the dense layer and the loop's
+    assert all(" %latent_decode" in c and "bf16[27,256,512,640]" in c
+               for c in calls), calls
+    scopes = op_scopes(hlo)
+    names = [c.split("=")[0].strip().lstrip("%") for c in calls]
+    assert all(in_scope(scopes.get(n), "mla_attn") for n in names), (
+        [scopes.get(n) for n in names])
+    whole = sorted(cache.latent.shape)
+    for name, op, dims, root in _kernels(hlo):
+        for d in dims:
+            d = sorted(x for x in d if x != 1)
+            if d == whole and op not in _VIEWS:
+                assert op == "dynamic-update-slice" or (
+                    op == "fusion" and root == "dynamic-update-slice"), (
+                    name, op, root)
+            # a layer's rows, whole or their latent part, in any dtype
+            assert d not in (sorted((256, 512, 640)),
+                             sorted((256, 512, 512))), (name, op, root)
