@@ -2,8 +2,8 @@
 
     PYTHONPATH=src python benchmarks/gen_experiments.py > EXPERIMENTS_tables.md
 
-Emits: §Dry-run summary (both meshes), §Roofline full table (single-pod
-baselines), and the variant rows for §Perf.
+Emits: §Dry-run summary (both meshes) and §Roofline full table
+(single-pod baselines).
 """
 from __future__ import annotations
 
@@ -109,31 +109,6 @@ def roofline_section():
     print()
 
 
-def variants_section():
-    print("### §Perf — variant measurements (hillclimbed cells)\n")
-    print("| cell | variant | compute s | memory s | collective s | "
-          "LCI bound s | dominant |")
-    print("|---|---|---|---|---|---|---|")
-    for p in sorted(glob.glob(os.path.join(ART, "*.json"))):
-        if p.endswith(".ops.json"):
-            continue
-        base = os.path.basename(p)[:-5]
-        parts = base.split("__")
-        if len(parts) != 4 or "+" not in parts[3]:
-            continue
-        a = json.load(open(p))
-        if a.get("status") != "ok":
-            continue
-        r = a["roofline"]
-        mode, *variants = parts[3].split("+")
-        print(f"| {a['arch']}/{a['shape']} | +{'+'.join(variants)} | "
-              f"{r['compute_s']:.4f} | {r['memory_s']:.4f} | "
-              f"{r['collective_s']:.4f} | {r.get('lci_bound_s', 0):.4f} | "
-              f"{r['dominant']} |")
-    print()
-
-
 if __name__ == "__main__":
     dryrun_section()
     roofline_section()
-    variants_section()

@@ -3,7 +3,7 @@
 Model code is written in *local view* (the shapes one device sees inside
 ``shard_map``), and every data movement goes through a :class:`Comm`, which
 is the in-graph analogue of an LCI *device*: a full set of communication
-resources the caller posts operations to.  Three deployments of the same
+resources the caller posts operations to.  Two deployments of the same
 model code:
 
 * **local** (``local_comm()``) — no mesh axes; every collective degenerates
@@ -12,8 +12,6 @@ model code:
   schedules of :mod:`repro.core.collectives` in the mode picked by
   ``CommConfig`` (BSP = paper's bulk-synchronous baseline, LCI_* = the
   paper's contribution).
-* **GSPMD** (``model_axis=None`` but constraints on) — the escape hatch for
-  comparing against XLA's automatic SPMD partitioner (§Perf).
 
 Axis conventions (DESIGN.md §5): ``model`` = TP/EP/SP axis; ``data`` =
 DP/FSDP axis (a tuple like ``("pod", "data")`` on the multi-pod mesh).
@@ -51,7 +49,6 @@ class Comm:
     config: CommConfig
     model_axis: AxisSpec = None
     data_axis: AxisSpec = None
-    fsdp: bool = True          # gather FSDP-dim weights in weight()
     # Endpoint spec: which resource bundle this Comm's collectives ride.
     # On the host runtime an EndpointSpec materializes as N devices; in
     # the in-graph layer the same knob selects the collective channel
@@ -218,7 +215,7 @@ class Comm:
         with the previous layer's compute; its VJP is the matching ring
         reduce(-scatter) of the weight gradient.
         """
-        if fsdp_axis is None or not self.fsdp:
+        if fsdp_axis is None:
             return w
         axes = _axes(self.data_axis)
         if not axes:
@@ -232,20 +229,6 @@ class Comm:
         axes = _axes(self.data_axis)
         for a in axes:
             x = lax.psum(x, a)
-        return x
-
-    def data_index(self) -> jax.Array:
-        """Flat index along the (possibly multi-axis) data dimension."""
-        idx = jnp.zeros((), jnp.int32)
-        for a in _axes(self.data_axis):
-            idx = idx * axis_size(a) + lax.axis_index(a)
-        return idx
-
-    def ag_data(self, x: jax.Array, *, axis: int) -> jax.Array:
-        """All-gather over the data axes along ``axis`` (tiny tensors —
-        the 2D-TP serving column reassembly)."""
-        for a in reversed(_axes(self.data_axis)):
-            x = C.all_gather(x, a, self.cfg, axis=axis)
         return x
 
     def pmean_data(self, x: jax.Array) -> jax.Array:

@@ -184,33 +184,12 @@ def collective_stats(hlo: str) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def build_cell(arch: str, shape_name: str, mesh, mode: CommMode,
-               *, remat: bool = True, tp2d: bool = False,
-               fsdp: bool = True, tp_mlp: bool = True,
-               wire_bf16: bool = False, pad_heads: bool = False):
+               *, remat: bool = True, wire_bf16: bool = False):
     """Returns (jitted_fn, abstract_args tuple)."""
-    import dataclasses as _dc
     cfg = get_config(arch)
-    if pad_heads:
-        # §Perf cell 4: pad head counts to the model-axis width so the
-        # attention and SSD branches shard instead of replicating
-        # (hymba: 25->32 q heads, 5->8 kv, 50->64 SSD heads via headdim)
-        def _pad(n, t):
-            return ((n + t - 1) // t) * t
-        t = cfg.tp_target
-        updates = {"n_heads": _pad(cfg.n_heads, t),
-                   "n_kv_heads": _pad(cfg.n_kv_heads, t // 2)}
-        if cfg.ssm_state and cfg.ssm_heads % t:
-            padded_heads = _pad(cfg.ssm_heads, t)
-            updates["ssm_headdim"] = cfg.ssm_d_inner // padded_heads
-        cfg = _dc.replace(cfg, **updates)
-    if not fsdp:
-        cfg = _dc.replace(cfg, fsdp_params=False)
-    if not tp_mlp:
-        cfg = _dc.replace(cfg, tp_mlp=False)
     shape = SHAPES[shape_name]
     model = build_model(cfg)
-    comm = make_comm(mesh, CommConfig(mode=mode, wire_bf16=wire_bf16),
-                     fsdp=cfg.fsdp_params)
+    comm = make_comm(mesh, CommConfig(mode=mode, wire_bf16=wire_bf16))
     daxes = data_axes(mesh)
 
     params_abs = jax.eval_shape(
@@ -260,12 +239,12 @@ def build_cell(arch: str, shape_name: str, mesh, mode: CommMode,
     from repro.serving.engine import make_serve_step
     b = shape.global_batch
     joint = b == 1
-    serve = make_serve_step(cfg, comm, joint_kv=joint, tp2d=tp2d)
+    serve = make_serve_step(cfg, comm, joint_kv=joint)
     cache_abs = jax.eval_shape(
         lambda: init_cache(cfg, shape.seq_len, b,
                            n_memory=n_memory_tokens(cfg)))
-    cspecs = cache_pspecs(cfg, batch=b, data_axis=daxes, tp2d=tp2d)
-    tok_spec = P() if (joint or tp2d) else P(daxes)
+    cspecs = cache_pspecs(cfg, batch=b, data_axis=daxes)
+    tok_spec = P() if joint else P(daxes)
     fn = shard_map(serve, mesh=mesh,
                        in_specs=(param_pspecs, cspecs, tok_spec),
                        out_specs=(tok_spec, cspecs), check_vma=False)
@@ -282,17 +261,12 @@ def build_cell(arch: str, shape_name: str, mesh, mode: CommMode,
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, mode: CommMode,
              *, remat: bool = True, save: bool = True,
-             tp2d: bool = False, fsdp: bool = True,
-             tp_mlp: bool = True, wire_bf16: bool = False,
-             pad_heads: bool = False) -> Dict[str, Any]:
+             wire_bf16: bool = False) -> Dict[str, Any]:
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     ok, why = shape_applicable(cfg, shape)
     mesh_name = "multi" if multi_pod else "single"
-    variant = ("+tp2d" if tp2d else "") + ("" if fsdp else "+nofsdp") \
-        + ("" if tp_mlp else "+notpmlp") \
-        + ("+wirebf16" if wire_bf16 else "") \
-        + ("+padheads" if pad_heads else "")
+    variant = "+wirebf16" if wire_bf16 else ""
     tag = f"{arch}__{shape_name}__{mesh_name}__{mode.value}{variant}"
     if not ok:
         art = {"cell": tag, "status": "skipped", "reason": why}
@@ -304,9 +278,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, mode: CommMode,
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
     jitted, raw_fn, args = build_cell(arch, shape_name, mesh, mode,
-                                      remat=remat, tp2d=tp2d, fsdp=fsdp,
-                                      tp_mlp=tp_mlp, wire_bf16=wire_bf16,
-                                      pad_heads=pad_heads)
+                                      remat=remat, wire_bf16=wire_bf16)
     lowered = jitted.lower(*args)
     t_lower = time.time() - t0
     t0 = time.time()
@@ -419,16 +391,8 @@ def main():
     ap.add_argument("--mesh", choices=("single", "multi"), default="single")
     ap.add_argument("--mode", default="lci_dedicated")
     ap.add_argument("--no-remat", action="store_true")
-    ap.add_argument("--tp2d", action="store_true",
-                    help="2D-TP weight-stationary serving (decode cells)")
-    ap.add_argument("--no-fsdp", action="store_true",
-                    help="replicate weights over data (small models)")
-    ap.add_argument("--no-tp-mlp", action="store_true",
-                    help="SP-only MLP: replicate d_ff over model")
     ap.add_argument("--wire-bf16", action="store_true",
                     help="bf16 ring accumulators (fp32 local adds)")
-    ap.add_argument("--pad-heads", action="store_true",
-                    help="pad head counts to shard over the model axis")
     ap.add_argument("--all", action="store_true",
                     help="run every applicable cell in subprocesses")
     ap.add_argument("--force", action="store_true",
@@ -465,9 +429,7 @@ def main():
         ap.error("--arch and --shape required (or --all)")
     run_cell(args.arch, args.shape, args.mesh == "multi",
              parse_mode(args.mode), remat=not args.no_remat,
-             tp2d=args.tp2d, fsdp=not args.no_fsdp,
-             tp_mlp=not args.no_tp_mlp, wire_bf16=args.wire_bf16,
-             pad_heads=args.pad_heads)
+             wire_bf16=args.wire_bf16)
 
 
 if __name__ == "__main__":

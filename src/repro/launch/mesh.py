@@ -46,12 +46,11 @@ def data_axes(mesh: Mesh) -> Tuple[str, ...]:
 
 
 def make_comm(mesh: Mesh, config: Optional[CommConfig] = None, *,
-              fsdp: bool = True,
               endpoint: Optional[EndpointSpec] = None) -> Comm:
     """Build the step Comm; ``endpoint`` picks the resource bundle the
     step's collectives ride (its width becomes the channel count)."""
     return Comm(config or CommConfig(), model_axis="model",
-                data_axis=data_axes(mesh), fsdp=fsdp, endpoint=endpoint)
+                data_axis=data_axes(mesh), endpoint=endpoint)
 
 
 def shard(mesh: Mesh, tree_pspecs):

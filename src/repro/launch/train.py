@@ -107,8 +107,7 @@ def train(arch: str, *, smoke: bool = False, dtype: Any = None,
                 f"the trainer's comm config accepts "
                 f"{sorted(_FIELD_TO_ATTR.values())}")
         comm = Comm(CommConfig(**{"mode": parse_mode(mode), **fields}),
-                    model_axis="model", data_axis="data",
-                    fsdp=cfg.fsdp_params)
+                    model_axis="model", data_axis="data")
         _, specs = model.abstract_params(key)
         pspecs = jax.tree_util.tree_map(lambda sp: sp.pspec(), specs)
         sspecs = TrainState(pspecs, OptState(P(), pspecs, pspecs, pspecs))

@@ -98,22 +98,20 @@ def init_mlp(pf: ParamFactory, cfg: ModelConfig, prefix: str = "",
     ff = d_ff if d_ff is not None else cfg.d_ff
     L = (stacked_layers,) if stacked_layers else ()
     st = bool(stacked_layers)
-    tp1 = 1 if cfg.tp_mlp else None
-    tp0 = 0 if cfg.tp_mlp else None
     p = {
         prefix + "w_out": pf.dense(prefix + "w_out", L + (ff, d),
-                                   tp_axis=tp0, fsdp_axis=1, stacked=st),
+                                   tp_axis=0, fsdp_axis=1, stacked=st),
     }
     if cfg.mlp in ("swiglu", "geglu"):
         # gate and up stored separately (same boundary argument as K/V)
         p[prefix + "w_gate"] = pf.dense(prefix + "w_gate", L + (d, ff),
-                                        tp_axis=tp1, fsdp_axis=0,
+                                        tp_axis=1, fsdp_axis=0,
                                         stacked=st)
         p[prefix + "w_up"] = pf.dense(prefix + "w_up", L + (d, ff),
-                                      tp_axis=tp1, fsdp_axis=0, stacked=st)
+                                      tp_axis=1, fsdp_axis=0, stacked=st)
     else:
         p[prefix + "w_in"] = pf.dense(prefix + "w_in", L + (d, ff),
-                                      tp_axis=tp1, fsdp_axis=0, stacked=st)
+                                      tp_axis=1, fsdp_axis=0, stacked=st)
     return p
 
 

@@ -109,17 +109,6 @@ class ModelConfig:
     # runtime meshes must divide the sharded dims identically
     tp_target: int = 16
 
-    # FSDP: shard the non-TP weight dim over the data axis at rest.  The
-    # right choice is size-dependent: ~free capacity for >8B models, pure
-    # collective overhead for small ones (§Perf cell 2) — hence a knob.
-    fsdp_params: bool = True
-
-    # TP for the MLP: sharding d_ff over the model axis buys memory but
-    # costs an activation gather+scatter per layer.  For small models the
-    # model axis should be SP-only: replicated MLP weights compute locally
-    # on sequence shards with ZERO collectives (§Perf cell 2).
-    tp_mlp: bool = True
-
     # ---------------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
@@ -290,10 +279,9 @@ def truncated_normal_init(key, shape, scale: float, dtype) -> jax.Array:
 class ParamFactory:
     """Init-time helper that records a ParamSpec for every created param."""
 
-    def __init__(self, key: jax.Array, dtype, fsdp: bool = True):
+    def __init__(self, key: jax.Array, dtype):
         self._key = key
         self.dtype = dtype
-        self.fsdp = fsdp
         self.specs: Dict[str, ParamSpec] = {}
 
     def next_key(self) -> jax.Array:
@@ -303,8 +291,6 @@ class ParamFactory:
     def dense(self, name: str, shape: Tuple[int, ...], *,
               tp_axis: Optional[int], fsdp_axis: Optional[int],
               stacked: bool = True, scale: float = 1.0) -> jax.Array:
-        if not self.fsdp:
-            fsdp_axis = None
         self.specs[name] = ParamSpec(tp_axis, fsdp_axis, stacked)
         return truncated_normal_init(self.next_key(), shape, scale,
                                      self.dtype)

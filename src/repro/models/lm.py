@@ -140,7 +140,7 @@ def _init_layer_stack(pf: ParamFactory, cfg: ModelConfig, L: int,
 def init_params(cfg: ModelConfig, key: jax.Array
                 ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """Returns (params, specs) — parallel pytrees."""
-    pf = ParamFactory(key, cfg.dtype, fsdp=cfg.fsdp_params)
+    pf = ParamFactory(key, cfg.dtype)
     d = cfg.d_model
     params: Dict[str, Any] = {}
     specs: Dict[str, Any] = {}
@@ -210,12 +210,6 @@ def _mlp_op(x, lp, cfg, comm, prefix: str = "") -> jax.Array:
     else:
         w_in = comm.weight(lp[prefix + "w_in"], fsdp_axis=0)
     w_out = comm.weight(lp[prefix + "w_out"], fsdp_axis=1)
-    if not cfg.tp_mlp:
-        # SP-only MLP: weights replicated over model, tokens stay
-        # seq-sharded — a pointwise op with ZERO collectives
-        from .layers import mlp_activation
-        h = mlp_activation(cfg.mlp, jnp.tensordot(x, w_in, axes=1))
-        return jnp.tensordot(h, w_out, axes=1)
     return mlp_block(x, w_in, w_out, cfg.mlp, comm)
 
 

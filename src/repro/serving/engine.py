@@ -49,15 +49,6 @@ the flash-decode max/sum-exp psum combine):
 Weights keep their at-rest layout: TP over ``model``; the FSDP dim over
 ``data`` is gathered per layer exactly like training ("FSDP-serving") —
 HBM-bound deployments trade ICI for memory.
-
-**2D-TP serving** (``tp2d=True``, the §Perf hillclimb result): weights are
-*stationary* in their 2-D (data × model) shards; instead of gathering a
-weight the engine slices the (tiny) activation along the contraction dim
-per data rank and psums partial products — per-matmul wire bytes drop
-from O(weight) to O(activation), turning decode from collective-bound
-into its natural memory-bound regime.  MoE expert weights keep the gather
-path (dispatch already owns the a2a); everything else goes through
-:func:`_wmul`.
 """
 from __future__ import annotations
 
@@ -170,10 +161,9 @@ def init_cache(cfg: ModelConfig, seq_len: int, batch: int, *,
 
 
 def cache_pspecs(cfg: ModelConfig, *, batch: int, model_axis="model",
-                 data_axis="data", tp2d: bool = False):
-    """PartitionSpecs for the cache: seq over model (+data when B==1 or
-    under 2D-TP serving, where the batch is replicated over data and the
-    data axis becomes extra sequence parallelism for the KV)."""
+                 data_axis="data"):
+    """PartitionSpecs for the cache: seq over model (+data when B==1),
+    batch over data."""
     from jax.sharding import PartitionSpec as P
     daxes = _axes(data_axis)
     joint = batch == 1
@@ -204,9 +194,8 @@ def cache_pspecs(cfg: ModelConfig, *, batch: int, model_axis="model",
 # ---------------------------------------------------------------------------
 
 def _embed_flat(tokens: jax.Array, emb: jax.Array, comm: Comm, *,
-                scale: bool, tp2d: bool = False) -> jax.Array:
-    """tokens (b,) replicated over model -> (b, d) via vocab-shard psum.
-    tp2d: emb columns stay data-sharded; reassemble with a tiny ag."""
+                scale: bool) -> jax.Array:
+    """tokens (b,) replicated over model -> (b, d) via vocab-shard psum."""
     v_local, d_loc = emb.shape
     rank = comm.model_index()
     local = tokens - rank * v_local
@@ -214,48 +203,22 @@ def _embed_flat(tokens: jax.Array, emb: jax.Array, comm: Comm, *,
     rows = jnp.take(emb, jnp.clip(local, 0, v_local - 1), axis=0)
     rows = jnp.where(valid[:, None], rows, 0).astype(jnp.float32)
     out = comm.psum_model(rows)
-    if tp2d:
-        out = comm.ag_data(out, axis=1)
     if scale:
         out = out * jnp.sqrt(jnp.float32(out.shape[-1]))
     return out.astype(emb.dtype)
 
 
-def _wmul(x, w, *, fsdp_axis: int, comm: Comm, tp2d: bool) -> jax.Array:
-    """``x @ w`` with w's FSDP dim either gathered (classic) or stationary.
-
-    tp2d & fsdp_axis == 0 (contraction dim data-sharded): slice the
-    activation's last dim to this data rank's rows, partial product, psum
-    over data — wire bytes O(activation), not O(weight).
-    tp2d & fsdp_axis == 1 (output dim data-sharded): local product, then
-    all-gather the (tiny) output columns over data.
-    """
-    if not tp2d or not comm.fsdp:
-        # tp2d presumes data-sharded weights; with fsdp off the weight is
-        # already full — plain local product
-        return jnp.tensordot(x, comm.weight(w, fsdp_axis=fsdp_axis),
-                             axes=1)
-    if fsdp_axis == 0:
-        k_l = w.shape[0]
-        start = comm.data_index() * k_l
-        xs = jax.lax.dynamic_slice_in_dim(x, start, k_l, axis=x.ndim - 1)
-        return comm.psum_data(jnp.tensordot(xs, w, axes=1))
-    y = jnp.tensordot(x, w, axes=1)
-    return comm.ag_data(y, axis=y.ndim - 1)
+def _in_proj(x, w, comm: Comm) -> jax.Array:
+    """``x @ w`` for a column-parallel weight, its FSDP dim (0) gathered."""
+    return jnp.tensordot(x, comm.weight(w, fsdp_axis=0), axes=1)
 
 
-def _row_parallel_out(x_loc, w, *, comm: Comm, tp2d: bool,
+def _row_parallel_out(x_loc, w, *, comm: Comm,
                       shard_model: bool) -> jax.Array:
-    """Row-parallel exit (wo / w_out): model psum + (tp2d) data column
-    gather, in the cheap order (reduce the narrow shard first)."""
-    if not tp2d or not comm.fsdp:
-        w_full = comm.weight(w, fsdp_axis=1)
-        y = jnp.tensordot(x_loc, w_full, axes=1)
-        return comm.psum_model(y) if shard_model else y
-    part = jnp.tensordot(x_loc, w, axes=1)        # (..., d/dp)
-    if shard_model:
-        part = comm.psum_model(part)
-    return comm.ag_data(part, axis=part.ndim - 1)
+    """Row-parallel exit (wo / w_out): gather the FSDP dim (1), local
+    product, psum over model when the rows are model-sharded."""
+    y = jnp.tensordot(x_loc, comm.weight(w, fsdp_axis=1), axes=1)
+    return comm.psum_model(y) if shard_model else y
 
 
 def _kv_axes(comm: Comm, *, joint: bool):
@@ -273,11 +236,6 @@ def _axes_index(comm: Comm, axes) -> jax.Array:
     for a in axes:
         idx = idx * axis_size(a) + jax.lax.axis_index(a)
     return idx
-
-
-def _axes_size(comm: Comm, axes) -> int:
-    import math
-    return math.prod([axis_size(a) for a in axes] or [1])
 
 
 def _psum_axes(x, axes):
@@ -305,8 +263,7 @@ def _write_row(buf, row, idx, at, owns):
 
 def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
                        idx, pos, window, *, joint_kv: bool,
-                       prefix: str = "", memory_kv=None,
-                       tp2d: bool = False, defer_out: bool = False):
+                       prefix: str = "", memory_kv=None):
     """One attention layer for a single token.
 
     x (b, d) replicated over model; k/v_all (L, S_loc, nkv, b, dh) the
@@ -318,7 +275,7 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
 
     # local projections, then tiny gathers to full heads
-    q = _wmul(x, lp[prefix + "wq"], fsdp_axis=0, comm=comm, tp2d=tp2d)
+    q = _in_proj(x, lp[prefix + "wq"], comm)
     if plan.shard_heads:
         q = comm.ag_seq(q.T, axis=0).T             # (b, nq*dh)
     q = q.reshape(-1, nq, dh)
@@ -327,10 +284,8 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
     if is_cross:
         k_new = v_new = None
     else:
-        k_new = _wmul(x, lp[prefix + "wk"], fsdp_axis=0, comm=comm,
-                      tp2d=tp2d)
-        v_new = _wmul(x, lp[prefix + "wv"], fsdp_axis=0, comm=comm,
-                      tp2d=tp2d)
+        k_new = _in_proj(x, lp[prefix + "wk"], comm)
+        v_new = _in_proj(x, lp[prefix + "wv"], comm)
         if plan.shard_kv:
             k_new = comm.ag_seq(k_new.T, axis=0).T
             v_new = comm.ag_seq(v_new.T, axis=0).T
@@ -341,30 +296,13 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
         q = rms_norm(q, lp[prefix + "q_norm"])
         if not is_cross:
             k_new = rms_norm(k_new, lp[prefix + "k_norm"])
-    # tp2d §Perf iteration 2: x/q are batch-replicated over data (the
-    # weight-stationary layout), but the attention inner loop is cheapest
-    # batch-SHARDED: slice this data rank's batch rows, attend against the
-    # classic (seq/model, batch/data) cache, combine over model only, and
-    # reassemble (b, d) once after the out-projection.
-    b_full = x.shape[0]
-    dp = comm.dp
-    batch_sharded = tp2d and not joint_kv and dp > 1 and b_full % dp == 0
-    if batch_sharded:
-        b_l = b_full // dp
-        bstart = comm.data_index() * b_l
-        q = jax.lax.dynamic_slice_in_dim(q, bstart, b_l, axis=0)
-        if not is_cross:
-            k_new = jax.lax.dynamic_slice_in_dim(k_new, bstart, b_l, axis=0)
-            v_new = jax.lax.dynamic_slice_in_dim(v_new, bstart, b_l, axis=0)
     if not is_cross:
         posv = jnp.full((1,), pos, jnp.int32)
         q = apply_rope(q[None], posv, cfg.rope_theta)[0]
         k_new = apply_rope(k_new[None], posv, cfg.rope_theta)[0]
 
         # cache write on the owning seq shard
-        axes = _kv_axes(comm, joint=joint_kv and not batch_sharded)
-        if batch_sharded:
-            axes = _kv_axes(comm, joint=False)
+        axes = _kv_axes(comm, joint=joint_kv)
         shard_len = k_all.shape[1]
         my_idx = _axes_index(comm, axes)
         my_start = my_idx * shard_len
@@ -390,28 +328,16 @@ def _decode_attn_layer(x, lp, cfg, comm: Comm, plan: TPPlan, k_all, v_all,
         attn = num / jnp.maximum(l, 1e-37)[..., None]
 
     attn = attn.reshape(-1, nq * dh).astype(x.dtype)
-    if batch_sharded:
-        # rejoin the batch rows BEFORE the out-projection (bf16, one
-        # ~b·h·dh stream); the stationary out-proj then produces complete
-        # rows — reassembling after would leave diagonal blocks (rank r
-        # holds rows r x wo-columns r and nobody computes the rest)
-        attn = comm.ag_data(attn, axis=0)             # (b, nq*dh)
     if plan.shard_heads:
         nq_l = plan.q_local(cfg)
         start = comm.model_index() * (nq_l * dh)
         attn_loc = jax.lax.dynamic_slice_in_dim(attn, start, nq_l * dh,
                                                 axis=1)
-        if defer_out:
-            return (jnp.tensordot(attn_loc, lp[prefix + "wo"], axes=1),
-                    k_all, v_all)
         out = _row_parallel_out(attn_loc, lp[prefix + "wo"], comm=comm,
-                                tp2d=tp2d, shard_model=True)
+                                shard_model=True)
     else:
-        if defer_out:
-            return (jnp.tensordot(attn, lp[prefix + "wo"], axes=1),
-                    k_all, v_all)
         out = _row_parallel_out(attn, lp[prefix + "wo"], comm=comm,
-                                tp2d=tp2d, shard_model=False)
+                                shard_model=False)
     return out, k_all, v_all
 
 
@@ -503,26 +429,20 @@ def _write_latent_rows(lat_all, rows, pos, comm: Comm, *, joint_kv: bool):
         return jax.lax.dynamic_update_slice(lat_all, new, start)
 
 
-def _decode_mlp(x, lp, cfg, comm: Comm, prefix: str = "",
-                tp2d: bool = False, defer_out: bool = False) -> jax.Array:
+def _decode_mlp(x, lp, cfg, comm: Comm, prefix: str = "") -> jax.Array:
     if cfg.mlp in ("swiglu", "geglu"):
         h = jnp.concatenate(
-            [_wmul(x, lp[prefix + "w_gate"], fsdp_axis=0, comm=comm,
-                   tp2d=tp2d),
-             _wmul(x, lp[prefix + "w_up"], fsdp_axis=0, comm=comm,
-                   tp2d=tp2d)], axis=-1)
+            [_in_proj(x, lp[prefix + "w_gate"], comm),
+             _in_proj(x, lp[prefix + "w_up"], comm)], axis=-1)
     else:
-        h = _wmul(x, lp[prefix + "w_in"], fsdp_axis=0, comm=comm,
-                  tp2d=tp2d)
+        h = _in_proj(x, lp[prefix + "w_in"], comm)
     h = mlp_activation(cfg.mlp, h)
-    if defer_out:
-        return jnp.tensordot(h, lp[prefix + "w_out"], axes=1)
     return _row_parallel_out(h, lp[prefix + "w_out"], comm=comm,
-                             tp2d=tp2d, shard_model=True)
+                             shard_model=True)
 
 
 def _decode_ssm(x, lp, cfg, comm: Comm, plan: TPPlan, state, conv_tail,
-                prefix: str = "ssm_", tp2d: bool = False):
+                prefix: str = "ssm_"):
     """x (b, d); state (b, H_loc, N, P); conv_tail (K-1, b, di_loc)."""
     def nm(s):
         return prefix + s
@@ -531,21 +451,10 @@ def _decode_ssm(x, lp, cfg, comm: Comm, plan: TPPlan, state, conv_tail,
     tp = comm.tp if plan.shard_ssm_heads else 1
     di_l, h_l = di // tp, h // tp
     zxdt = jnp.concatenate(
-        [_wmul(x, lp[nm("w_z")], fsdp_axis=0, comm=comm, tp2d=tp2d),
-         _wmul(x, lp[nm("w_x")], fsdp_axis=0, comm=comm, tp2d=tp2d),
-         _wmul(x, lp[nm("w_dt")], fsdp_axis=0, comm=comm, tp2d=tp2d)],
-        axis=-1)
-    bc = _wmul(x, lp[nm("w_bc")], fsdp_axis=0, comm=comm, tp2d=tp2d)
-    # tp2d: the recurrent state/conv caches are batch-sharded over data;
-    # slice this rank's batch rows for the recurrence, rejoin after
-    b_full = x.shape[0]
-    dp = comm.dp
-    batch_sharded = tp2d and dp > 1 and b_full % dp == 0
-    if batch_sharded:
-        b_l = b_full // dp
-        bstart = comm.data_index() * b_l
-        zxdt = jax.lax.dynamic_slice_in_dim(zxdt, bstart, b_l, axis=0)
-        bc = jax.lax.dynamic_slice_in_dim(bc, bstart, b_l, axis=0)
+        [_in_proj(x, lp[nm("w_z")], comm),
+         _in_proj(x, lp[nm("w_x")], comm),
+         _in_proj(x, lp[nm("w_dt")], comm)], axis=-1)
+    bc = _in_proj(x, lp[nm("w_bc")], comm)
     z, xs, dt_raw = jnp.split(zxdt, [di_l, 2 * di_l], axis=-1)
     b_t, c_t = jnp.split(bc, 2, axis=-1)
     g, n = cfg.ssm_groups, cfg.ssm_state
@@ -575,9 +484,7 @@ def _decode_ssm(x, lp, cfg, comm: Comm, plan: TPPlan, state, conv_tail,
         denom = di
     yf = yf * jax.lax.rsqrt(ssq / denom + 1e-6)
     y = (yf * lp[nm("norm_w")].astype(jnp.float32)).astype(x.dtype)
-    if batch_sharded:
-        y = comm.ag_data(y, axis=0)          # rejoin rows pre-out-proj
-    out = _row_parallel_out(y, lp[nm("w_out")], comm=comm, tp2d=tp2d,
+    out = _row_parallel_out(y, lp[nm("w_out")], comm=comm,
                             shard_model=plan.shard_ssm_heads)
     return out, state, conv_tail
 
@@ -587,7 +494,7 @@ def _decode_ssm(x, lp, cfg, comm: Comm, plan: TPPlan, state, conv_tail,
 # ---------------------------------------------------------------------------
 
 def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
-                    joint_kv: bool = False, tp2d: bool = False):
+                    joint_kv: bool = False):
     """Build ``serve_step(params, cache, tokens) -> (next_tokens, cache')``.
 
     tokens: (b,) int32 — the tokens decoded at position ``cache.length``;
@@ -600,10 +507,8 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
     def serve_step(params, cache: DecodeCache, tokens: jax.Array):
         plan = tp_plan(cfg, comm.tp)
         pos = cache.length
-        emb_w = (params["emb"] if tp2d
-                 else comm.weight(params["emb"], fsdp_axis=1))
-        x = _embed_flat(tokens, emb_w, comm,
-                        scale=cfg.name.startswith("gemma"), tp2d=tp2d)
+        x = _embed_flat(tokens, comm.weight(params["emb"], fsdp_axis=1),
+                        comm, scale=cfg.name.startswith("gemma"))
 
         is_vlm = cfg.family == "vlm"
         n_cross = _n_cross(cfg)
@@ -625,20 +530,18 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 ct = call_[idx] if call_ is not None else None
 
             if cfg.family == "ssm":
-                out, st, ct = _decode_ssm(h, lp, cfg, comm, plan, st, ct,
-                                          tp2d=tp2d)
+                out, st, ct = _decode_ssm(h, lp, cfg, comm, plan, st, ct)
                 xc = xc + out
             elif cfg.family == "hybrid":
                 a_out, kall, vall = _decode_attn_layer(
                     h, lp, cfg, comm, plan, kall, vall, idx, pos, window,
-                    joint_kv=joint_kv, tp2d=tp2d)
-                s_out, st, ct = _decode_ssm(h, lp, cfg, comm, plan, st, ct,
-                                            tp2d=tp2d)
+                    joint_kv=joint_kv)
+                s_out, st, ct = _decode_ssm(h, lp, cfg, comm, plan, st, ct)
                 mix = 0.5 * (rms_norm(a_out, lp["mix_norm_a"])
                              + rms_norm(s_out, lp["mix_norm_s"]))
                 xc = xc + mix
                 h2 = apply_norm(cfg.norm, xc, lp.get("norm2"))
-                xc = xc + _decode_mlp(h2, lp, cfg, comm, tp2d=tp2d)
+                xc = xc + _decode_mlp(h2, lp, cfg, comm)
             else:
                 if cfg.is_mla:
                     a_out, row = _decode_mla(h, lp, cfg, comm, cache.latent,
@@ -646,20 +549,9 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 else:
                     a_out, kall, vall = _decode_attn_layer(
                         h, lp, cfg, comm, plan, kall, vall, idx, pos, window,
-                        joint_kv=joint_kv, tp2d=tp2d,
-                        defer_out=tp2d and cfg.parallel_block)
+                        joint_kv=joint_kv)
                 if cfg.parallel_block:
-                    # §Perf iteration 3: under tp2d, attention and MLP
-                    # write the SAME residual; add their pre-reduction
-                    # partials and pay one psum_model + one column gather
-                    if tp2d:
-                        pm = _decode_mlp(h, lp, cfg, comm, tp2d=True,
-                                         defer_out=True)
-                        combined = comm.psum_model(a_out + pm)
-                        xc = xc + comm.ag_data(combined,
-                                               axis=combined.ndim - 1)
-                    else:
-                        xc = xc + a_out + _decode_mlp(h, lp, cfg, comm)
+                    xc = xc + a_out + _decode_mlp(h, lp, cfg, comm)
                 else:
                     xc = xc + a_out
                     if cfg.is_encdec and aux_kv is not None:
@@ -667,7 +559,7 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                         x_out, _, _ = _decode_attn_layer(
                             hx, lp, cfg, comm, plan, None, None, None, pos, 0,
                             joint_kv=joint_kv, prefix="x_",
-                            memory_kv=aux_kv, tp2d=tp2d)
+                            memory_kv=aux_kv)
                         xc = xc + x_out
                     h2 = apply_norm(cfg.norm, xc, lp.get("norm2"),
                                     cfg.norm_eps)
@@ -675,8 +567,7 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                         xc, load = _decode_moe(xc, h2, lp, load,
                                                idx - cfg.first_dense_layers)
                     else:
-                        xc = xc + _decode_mlp(h2, lp, cfg, comm,
-                                              tp2d=tp2d)
+                        xc = xc + _decode_mlp(h2, lp, cfg, comm)
 
             with jax.named_scope(CACHE_IO):
                 if sall is not None and st is not None:
@@ -686,12 +577,11 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
 
         def _decode_moe(xc, h2, lp, load, j):
             """Dropless routed experts (the gather path for their weights)
-            plus the shared expert (rides tp2d); the held experts' load
-            is added to row ``j`` of the counter."""
+            plus the shared expert; the held experts' load is added to
+            row ``j`` of the counter."""
             mo, n = moe_decode(h2, lp, cfg, comm)
             if cfg.shared_expert_ff:
-                mo = mo + _decode_mlp(h2, lp, cfg, comm, prefix="shared_",
-                                      tp2d=tp2d)
+                mo = mo + _decode_mlp(h2, lp, cfg, comm, prefix="shared_")
             load = load.at[0, j].add(n[0]).at[1, j].max(n[0]) \
                 .at[2, j].add(n[1])
             return xc + mo, load
@@ -742,12 +632,11 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
                 x_out, _, _ = _decode_attn_layer(
                     hx, cross_lp, cfg, comm, tp_plan(cfg, comm.tp), None,
                     None, None, pos, 0, joint_kv=joint_kv, prefix="x_",
-                    memory_kv=(xk, xv), tp2d=tp2d)
+                    memory_kv=(xk, xv))
                 xc = xc + jnp.tanh(cross_lp["gate_attn"]).astype(xc.dtype) \
                     * x_out
                 hm = rms_norm(xc, cross_lp["normm"])
-                ff = _decode_mlp(hm, cross_lp, cfg, comm, prefix="xm_",
-                                 tp2d=tp2d)
+                ff = _decode_mlp(hm, cross_lp, cfg, comm, prefix="xm_")
                 xc = xc + jnp.tanh(cross_lp["gate_mlp"]).astype(xc.dtype) \
                     * ff
                 return (xc,) + c[1:], ()
@@ -771,23 +660,9 @@ def make_serve_step(cfg: ModelConfig, comm: Optional[Comm] = None, *,
         xc, kall, vall, sall, call_, load = carry
         xc = apply_norm("rmsnorm" if cfg.norm == "rmsnorm" else "layernorm",
                         xc, params["final_norm"], cfg.norm_eps)
-        head = params.get("lm_head", params["emb"])
-        if tp2d:
-            # head columns (d) stay data-sharded: slice x, partial logits,
-            # psum over data; vocab masking/argmax unchanged
-            d_l = head.shape[1]
-            start = comm.data_index() * d_l
-            x_slice = jax.lax.dynamic_slice_in_dim(xc, start, d_l, axis=1)
-            logits = jnp.tensordot(x_slice.astype(jnp.float32),
-                                   head.astype(jnp.float32).T, axes=1)
-            logits = comm.psum_data(logits)
-            v_local = head.shape[0]
-            gid = comm.model_index() * v_local + jnp.arange(v_local)
-            logits = jnp.where(gid[None, :] < cfg.vocab, logits, -1e30)
-        else:
-            head_full = comm.weight(head, fsdp_axis=1)
-            logits = lm_head_logits(xc, head_full, comm,
-                                    real_vocab=cfg.vocab)
+        head = comm.weight(params.get("lm_head", params["emb"]),
+                           fsdp_axis=1)
+        logits = lm_head_logits(xc, head, comm, real_vocab=cfg.vocab)
         next_tokens = greedy_sample(logits, comm)
         blocks = cache.latent_blocks
         if cfg.is_mla:
